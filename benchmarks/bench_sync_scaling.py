@@ -2,7 +2,11 @@
 
 Threads -> data-parallel shards: the WorkMeter's dynamic counters need a
 cross-shard psum, so hook cost grows with the DP degree.  Each shard count
-runs in a subprocess (XLA locks the host device count at first init)."""
+runs in a subprocess (XLA locks the host device count at first init).
+
+A CPU-only rehearsal: the children fake host devices with ``XLA_FLAGS`` and
+start after the parent has configured JAX, so never run it on a TPU, where
+one process owns the chip."""
 from __future__ import annotations
 
 import json

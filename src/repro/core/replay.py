@@ -142,9 +142,9 @@ def measure_full_run(runner: StepRunner, n_steps: int,
     One throwaway step first so jit compilation never pollutes the
     measurement (all platforms are timed post-compile, like the paper's
     post-warmup hardware runs)."""
-    state = runner.reset(start)
-    state = runner.run_step(state, start)
+    state = runner.run_step(runner.reset(start), start)
     runner.sync(state)
+    del state                  # never hold two states (one fills a chip)
     state = runner.reset(start)
     t0 = time.perf_counter()
     for s in range(start, n_steps):

@@ -19,11 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                        # jax >= 0.6
-    _shard_map = jax.shard_map
-except AttributeError:                      # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
           mesh: Mesh, axis: str = "stage"):
@@ -61,17 +56,14 @@ def gpipe(stage_fn: Callable[[Any, jax.Array], jax.Array],
         outs0 = jnp.zeros_like(xs)
         # the carry becomes device-varying over the stage axis inside the
         # loop; mark the initial values accordingly (shard_map VMA typing)
-        try:
-            buf0 = jax.lax.pcast(buf0, (axis,), to="varying")
-            outs0 = jax.lax.pcast(outs0, (axis,), to="varying")
-        except (AttributeError, TypeError):      # older jax: no VMA tracking
-            pass
+        buf0 = jax.lax.pcast(buf0, (axis,), to="varying")
+        outs0 = jax.lax.pcast(outs0, (axis,), to="varying")
         (_, outs), _ = jax.lax.scan(tick, (buf0, outs0), jnp.arange(total))
         # replicate the last stage's outputs to every stage
         mask = (idx == S - 1).astype(outs.dtype)
         return jax.lax.psum(outs * mask, axis)
 
-    return _shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

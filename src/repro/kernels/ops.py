@@ -1,12 +1,16 @@
 """jit'd wrappers assembling full operations from the Pallas kernels.
 
+The kernels compile with Mosaic on a TPU backend and run in Pallas
+interpret mode on any other backend: the choice follows
+``jax.default_backend()``, so a TPU run never falls back to the
+interpreter.
+
 ``ssd`` composes the intra-chunk kernel with the cheap inter-chunk
 recurrence (lax.scan) and the C·h_in inter-chunk output term.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -16,24 +20,27 @@ from repro.kernels.flash_decode import flash_decode as _flash_decode
 from repro.kernels.ssd import ssd_intra
 
 
+def interpret_mode() -> bool:
+    """Interpret the kernels everywhere but on a TPU backend."""
+    return jax.default_backend() != "tpu"
+
+
 def flash_attention(q, k, v, q_pos=None, k_pos=None, *, group: int,
                     causal: bool = True, window=None, cap: float = 0.0,
-                    bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    bq: int = 128, bk: int = 128) -> jax.Array:
     """Model-facing signature (positions are arange; rope pre-applied)."""
     return _flash(q, k, v, group=group, causal=causal, window=window,
-                  cap=cap, bq=bq, bk=bk, interpret=interpret)
+                  cap=cap, bq=bq, bk=bk, interpret=interpret_mode())
 
 
 def flash_decode(q, k_cache, v_cache, lengths, *, group: int, window=None,
-                 cap: float = 0.0, bk: int = 256,
-                 interpret: bool = True) -> jax.Array:
+                 cap: float = 0.0, bk: int = 256) -> jax.Array:
     return _flash_decode(q, k_cache, v_cache, lengths, group=group,
-                         window=window, cap=cap, bk=bk, interpret=interpret)
+                         window=window, cap=cap, bk=bk,
+                         interpret=interpret_mode())
 
 
-def ssd(xh, dt, A, Bp, Cp, *, chunk: int = 256,
-        interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+def ssd(xh, dt, A, Bp, Cp, *, chunk: int = 256) -> Tuple[jax.Array, jax.Array]:
     """Full SSD layer: Pallas intra-chunk + lax.scan inter-chunk.
     Returns (y [B,S,nh,hp] f32, h_final [B,nh,hp,N] f32)."""
     b, s, nh, hp = xh.shape
@@ -41,7 +48,7 @@ def ssd(xh, dt, A, Bp, Cp, *, chunk: int = 256,
     q = min(chunk, s)
     nc = -(-s // q)
     y_intra, s_chunk, dec, cum = ssd_intra(xh, dt, A, Bp, Cp, chunk,
-                                           interpret=interpret)
+                                           interpret=interpret_mode())
     pad = nc * q - s
     Cq = (jnp.pad(Cp, ((0, 0), (0, pad), (0, 0))) if pad else Cp) \
         .astype(jnp.float32).reshape(b, nc, q, n)

@@ -5,62 +5,58 @@ masked-decay matmuls — the MXU hot spot) and (b) an O(nchunk) sequential
 state recurrence.  The kernel computes, per (batch, head, chunk):
 
     y_intra = (L ∘ (C B^T)) Xdt          [q, hp]
-    s_chunk = B^T (decay_out ∘ Xdt)      [hp, N] contribution to the state
-    decay   = exp(cum[-1])               total chunk decay
+    s_chunk = (decay_out ∘ Xdt)^T B      [hp, N] contribution to the state
 
-The cheap inter-chunk recurrence + C·h_in inter term run as a lax.scan in
-``ops.ssd`` — this mirrors how the CUDA SSD kernel is adapted to the TPU's
-(MXU + sequential-grid) execution model (DESIGN.md §2 hardware adaptation).
+The wrapper puts heads before sequence (x: [B,nh,S,hp], dt: [B,nh,S,1]) so
+every block ends in an (8,128)-tileable pair; A sits in SMEM.  The
+within-chunk cumulative log decay is a masked reduction over a [q,q]
+tile (Mosaic has no cumsum).  The cheap inter-chunk recurrence + C·h_in
+inter term run as a lax.scan in ``ops.ssd`` — this mirrors how the CUDA
+SSD kernel is adapted to the TPU's (MXU + sequential-grid) execution model
+(DESIGN.md §2 hardware adaptation).
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:                                   # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref,
-                y_ref, s_ref, dec_ref, *, q: int):
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # [q, hp]
-    dt = dt_ref[0, :, 0].astype(jnp.float32)           # [q]
-    A = a_ref[0]                                       # scalar (<0)
-    B = b_ref[0, 0].astype(jnp.float32)                # [q, N]
-    C = c_ref[0, 0].astype(jnp.float32)                # [q, N]
+def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_ref, *, q: int):
+    x = x_ref[...].astype(jnp.float32)                 # [q, hp]
+    dt = dt_ref[...].astype(jnp.float32)               # [q, 1]
+    A = a_ref[pl.program_id(1)]                        # scalar (<0)
+    B = b_ref[...].astype(jnp.float32)                 # [q, N]
+    C = c_ref[...].astype(jnp.float32)                 # [q, N]
 
     la = dt * A                                        # log decay per step
-    cum = jnp.cumsum(la)                               # [q]
-    xdt = x * dt[:, None]
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    cum_r = jnp.sum(jnp.where(row <= col, la, 0.0), axis=0,
+                    keepdims=True)                     # [1, q] cumsum
+    cum_c = jnp.sum(jnp.where(row == col, cum_r, 0.0), axis=1,
+                    keepdims=True)                     # [q, 1] same, as column
+    xdt = x * dt
 
-    rel = cum[:, None] - cum[None, :]                  # [q, q]
-    tri = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    Lk = jnp.exp(jnp.where(tri, rel, -jnp.inf))
+    Lk = jnp.exp(jnp.where(row >= col, cum_c - cum_r, -jnp.inf))
     cb = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)   # [q,q]
-    y_ref[0, :, 0, :] = (jax.lax.dot_general(
+    y_ref[...] = jax.lax.dot_general(
         Lk * cb, xdt, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)).astype(y_ref.dtype)
+        preferred_element_type=jnp.float32).astype(y_ref.dtype)
 
-    decay_out = jnp.exp(cum[-1] - cum)                 # [q]
-    s_chunk = jax.lax.dot_general(
-        xdt * decay_out[:, None], B, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [hp, N]
-    s_ref[0, 0, 0] = s_chunk.astype(s_ref.dtype)
-    dec_ref[0, 0, 0] = jnp.exp(cum[-1])
+    total = jnp.sum(la, axis=0, keepdims=True)         # [1, 1]
+    decay_out = jnp.exp(total - cum_c)                 # [q, 1]
+    s_ref[...] = jax.lax.dot_general(
+        xdt * decay_out, B, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(s_ref.dtype)   # [hp, N]
 
 
 def ssd_intra(xh: jax.Array, dt: jax.Array, A: jax.Array, Bp: jax.Array,
-              Cp: jax.Array, chunk: int, *, interpret: bool = True):
+              Cp: jax.Array, chunk: int, *, interpret: bool):
     """xh: [B,S,nh,hp]; dt: [B,S,nh] f32; A: [nh]; Bp/Cp: [B,S,N].
     Returns (y_intra [B,S,nh,hp] f32, s_chunk [B,nc,nh,hp,N] f32,
     decay [B,nc,nh] f32, cum [B,nc,q,nh])."""
@@ -75,33 +71,37 @@ def ssd_intra(xh: jax.Array, dt: jax.Array, A: jax.Array, Bp: jax.Array,
         Bp = jnp.pad(Bp, ((0, 0), (0, pad), (0, 0)))
         Cp = jnp.pad(Cp, ((0, 0), (0, pad), (0, 0)))
     s_pad = nc * q
-    Bq = Bp.reshape(b, nc, q, n)
-    Cq = Cp.reshape(b, nc, q, n)
 
     kernel = functools.partial(_ssd_kernel, q=q)
-    y, s_chunk, dec = pl.pallas_call(
+    head_seq = lambda bb, hh, cc: (bb, hh, cc, 0)      # noqa: E731
+    seq = lambda bb, hh, cc: (bb, cc, 0)               # noqa: E731
+    y, s_chunk = pl.pallas_call(
         kernel,
         grid=(b, nh, nc),
         in_specs=[
-            pl.BlockSpec((1, q, 1, hp), lambda bb, hh, cc: (bb, cc, hh, 0)),
-            pl.BlockSpec((1, q, 1), lambda bb, hh, cc: (bb, cc, hh)),
-            pl.BlockSpec((1,), lambda bb, hh, cc: (hh,)),
-            pl.BlockSpec((1, 1, q, n), lambda bb, hh, cc: (bb, cc, 0, 0)),
-            pl.BlockSpec((1, 1, q, n), lambda bb, hh, cc: (bb, cc, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((None, None, q, hp), head_seq),
+            pl.BlockSpec((None, None, q, 1), head_seq),
+            pl.BlockSpec((None, q, n), seq),
+            pl.BlockSpec((None, q, n), seq),
         ],
         out_specs=[
-            pl.BlockSpec((1, q, 1, hp), lambda bb, hh, cc: (bb, cc, hh, 0)),
-            pl.BlockSpec((1, 1, 1, hp, n), lambda bb, hh, cc: (bb, cc, hh, 0, 0)),
-            pl.BlockSpec((1, 1, 1), lambda bb, hh, cc: (bb, cc, hh)),
+            pl.BlockSpec((None, None, q, hp), head_seq),
+            pl.BlockSpec((None, None, None, hp, n),
+                         lambda bb, hh, cc: (bb, hh, cc, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s_pad, nh, hp), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc, nh, hp, n), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc, nh), jnp.float32),
+            jax.ShapeDtypeStruct((b, nh, s_pad, hp), jnp.float32),
+            jax.ShapeDtypeStruct((b, nh, nc, hp, n), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
-    )(xh, dt, A, Bq, Cq)
-    # cum is recomputed cheaply outside for the inter-chunk term
+    )(A.astype(jnp.float32), jnp.swapaxes(xh, 1, 2),
+      jnp.swapaxes(dt, 1, 2)[..., None], Bp, Cp)
+    # cum is recomputed cheaply outside for the inter-chunk term and the
+    # per-chunk total decay
     la = (dt * A[None, None, :]).reshape(b, nc, q, nh)
     cum = jnp.cumsum(la, axis=2)
-    return y, s_chunk, dec, cum
+    dec = jnp.exp(cum[:, :, -1])
+    return (jnp.swapaxes(y, 1, 2), jnp.moveaxis(s_chunk, 2, 1), dec, cum)

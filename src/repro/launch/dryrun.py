@@ -6,6 +6,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 ShapeDtypeStruct inputs (no allocation) on the production meshes, and record
 memory/cost/collective analyses for the roofline (EXPERIMENTS.md §Dry-run).
 
+A CPU-only rehearsal tool: importing this module sets ``XLA_FLAGS`` to 512
+host devices, and ``--all`` starts one child process per cell after the
+parent has configured JAX.  Never point it at a TPU, where one process owns
+the chip; ``chip_smoke.py`` is the on-chip check.
+
 Usage:
     python -m repro.launch.dryrun --arch qwen3-1.7b --shape train_4k --mesh single
     python -m repro.launch.dryrun --all          # every cell, subprocess-per-cell
@@ -322,6 +327,8 @@ def main() -> int:
     ap.add_argument("--tag", default="")
     args = ap.parse_args()
     os.makedirs(args.out, exist_ok=True)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.all:
         failures = []

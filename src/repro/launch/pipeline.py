@@ -46,6 +46,7 @@ def build_config(args) -> "PipelineConfig":
         steps=args.steps, seq_len=args.seq_len, batch=args.batch,
         interval_steps=args.interval_steps, seed=args.seed,
         reduce=args.reduced,
+        n_layers=args.n_layers,
         warmup_intervals=args.warmup_intervals,
         search_distance=args.search_distance,
         ckpt_every=args.ckpt_every,
@@ -65,6 +66,9 @@ def main():
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
                     help="reduced same-family config (CPU-feasible)")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="cut the published depth to this many layers "
+                         "(widths stay as published)")
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--seq-len", type=int, default=32)
     ap.add_argument("--batch", type=int, default=4)
@@ -125,6 +129,8 @@ def main():
                     help="print the human metrics table after the run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro import obs
     obs.log.setup()
     if args.trace:

@@ -34,6 +34,8 @@ def main():
 
     import jax
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro import obs
     obs.log.setup()                       # key=value lines, REPRO_LOG_LEVEL
     obs.configure_from_env()              # spans if REPRO_TRACE is set

@@ -40,6 +40,8 @@ def main():
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro import obs
     obs.log.setup()                       # key=value lines, REPRO_LOG_LEVEL
     obs.configure_from_env()              # spans if REPRO_TRACE is set

@@ -68,6 +68,9 @@ class PipelineConfig:
     interval_steps: float = 2.5
     seed: int = 0
     reduce: bool = True
+    # depth cut of the published config (None = published depth); every
+    # width stays as published
+    n_layers: Optional[int] = None
     warmup_intervals: int = 1
     search_distance: float = 0.0
     ckpt_every: int = 0
@@ -110,7 +113,11 @@ class PipelineConfig:
 
     def base_cfg(self) -> ArchConfig:
         cfg = get_config(self.arch)
-        return reduced(cfg, seq=self.seq_len) if self.reduce else cfg
+        if self.reduce:
+            cfg = reduced(cfg, seq=self.seq_len)
+        if self.n_layers is not None:
+            cfg = dataclasses.replace(cfg, n_layers=self.n_layers)
+        return cfg
 
     def arch_for(self, platform: str) -> ArchConfig:
         return platform_config(self.base_cfg(), platform)
@@ -185,7 +192,7 @@ class PipelineContext:
                     batch=cfg.batch, interval_steps=cfg.interval_steps,
                     seed=cfg.seed,
                     instrument=(platform == cfg.profile_platform_name),
-                    defer_analysis=cfg.defer_analysis, donate=False)
+                    defer_analysis=cfg.defer_analysis)
                 with self._lock:
                     self._trainers[platform] = tr
         return self._trainers[platform]

@@ -33,8 +33,13 @@ def init_train_state(model: Model, key: jax.Array, opt_cfg: AdamWConfig,
                        jax.random.fold_in(key, 1), meter)
     # JAX caches equal constants: distinct zero leaves can alias the same
     # buffer, which breaks donate_argnums ("donate the same buffer twice").
-    # Copy each leaf so every leaf owns its buffer.
-    return jax.tree.map(lambda x: x.copy() if hasattr(x, "copy") else x, state)
+    # Copy each leaf so every leaf owns its buffer, one leaf at a time so
+    # the device never holds two copies of the whole state.
+    leaves, treedef = jax.tree.flatten(state)
+    del state, params, opt, meter
+    for i, x in enumerate(leaves):
+        leaves[i] = x.copy() if hasattr(x, "copy") else x
+    return jax.tree.unflatten(treedef, leaves)
 
 
 def make_train_step(model: Model, opt_cfg: AdamWConfig, lr_fn: Callable,

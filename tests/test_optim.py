@@ -76,7 +76,6 @@ def test_compressed_psum_matches_sum_shardmap():
     """int8 EF psum under shard_map on 1 device == plain sum (n=1)."""
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.optim.grad_compress import compressed_psum, init_error_feedback
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
@@ -86,7 +85,7 @@ def test_compressed_psum_matches_sum_shardmap():
     def f(g, ef):
         return compressed_psum(g, ef, "dp")
 
-    out, new_ef = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                            out_specs=(P(), P()))(g, ef)
+    out, new_ef = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                out_specs=(P(), P()))(g, ef)
     np.testing.assert_allclose(np.asarray(out["w"]), np.asarray(g["w"]),
                                atol=1e-2)

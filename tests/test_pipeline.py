@@ -15,7 +15,8 @@ sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.distributed.pipeline import gpipe
 
-mesh = jax.make_mesh((4,), ("stage",))
+mesh = jax.make_mesh((4,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 S, M, B, D = 4, 6, 2, 8
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (S, D, D)) * 0.3

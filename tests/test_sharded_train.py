@@ -46,7 +46,8 @@ for _ in range(3):
     losses1.append(float(met["loss"]))
 
 # ---- 4x2 mesh, 2D sharded ----------------------------------------------
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 plan = logical_rules(mesh, mode="train")
 with mesh, use_rules(plan):
     m2 = build_model(cfg, plan)
