@@ -1,9 +1,10 @@
 """``repro.obs`` — unified tracing + metrics across the nugget lifecycle.
 
-Zero-dependency observability with three pieces (see
+Observability with three pieces (see
 ``docs/observability.md``):
 
-- :mod:`repro.obs.trace`   — nestable spans, JSONL sink, Chrome-trace export,
+- :mod:`repro.obs.trace`   — nestable spans, JSONL sink, Chrome-trace export;
+  an enabled span is also a ``jax.profiler.TraceAnnotation``,
 - :mod:`repro.obs.metrics` — counters / gauges / histograms + snapshots,
 - :mod:`repro.obs.log`     — structured ``key=value`` logging
   (``REPRO_LOG_LEVEL``).

@@ -11,6 +11,11 @@ nugget lifecycle — ``pipeline.run`` > ``stage.profile`` >
   ``chrome://tracing`` and https://ui.perfetto.dev load directly, so a full
   pipeline run can be inspected in a real trace viewer.
 
+An enabled span also enters a ``jax.profiler.TraceAnnotation`` of its
+name, so a profiler trace taken meanwhile shows it on its host plane, on the
+device trace's clock, beside the device's operations (Perfetto,
+TensorBoard).  jax is imported lazily, by the first enabled span.
+
 Disabled (the default) the tracer is a handful of attribute reads per
 ``span()`` call — the hot-loop budget is enforced by
 ``benchmarks/bench_hook_overhead.py`` (<2%% of a training step).  Span
@@ -19,6 +24,7 @@ lock, so concurrent stages/chunks trace safely.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
@@ -31,12 +37,22 @@ _PH_SPAN = "X"
 _PH_INSTANT = "i"
 
 
+@functools.cache
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, imported by the first enabled
+    span and kept."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
 class Span:
     """One open span.  Use as a context manager (``with tracer.span(...)``);
     ``event()`` records instants inside it, ``set()`` attaches attributes
-    that land in the Chrome-trace ``args`` dict."""
+    that land in the Chrome-trace ``args`` dict.  While open it is also a
+    ``jax.profiler.TraceAnnotation`` of the same name."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0", "_tid", "_depth")
+    __slots__ = ("tracer", "name", "attrs", "t0", "_tid", "_depth",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.tracer = tracer
@@ -45,9 +61,12 @@ class Span:
         self.t0 = 0.0
         self._tid = 0
         self._depth = 0
+        self._annotation = None
 
     # -- context manager ----------------------------------------------
     def __enter__(self) -> "Span":
+        self._annotation = _annotation_type()(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         self._tid = threading.get_ident()
         self._depth = self.tracer._push()
@@ -55,6 +74,7 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         self.tracer._pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -212,14 +232,6 @@ class Tracer:
         os.makedirs(d, exist_ok=True)
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f, indent=1)
-        return path
-
-    def write_jsonl(self, path: str) -> str:
-        d = os.path.dirname(os.path.abspath(path))
-        os.makedirs(d, exist_ok=True)
-        with open(path, "w") as f:
-            for ev in self.events():
-                f.write(json.dumps(ev) + "\n")
         return path
 
 

@@ -6,7 +6,9 @@ batch (inactive slots masked).  The engine is a *profiled program*: prefill
 and decode iterations emit different hook streams (merged BlockTable), so
 serving intervals genuinely vary in composition — the serving analogue of the
 paper's multi-phase workloads.  ``snapshot()``/``restore()`` capture engine
-state for replay resets and elastic migration.
+state for replay resets and elastic migration.  Each iteration is a
+``serve.step`` span with a span per phase inside it (see
+``docs/observability.md``).
 """
 from __future__ import annotations
 
@@ -105,66 +107,90 @@ class ServeEngine:
         self.queue.append(req)
 
     # ------------------------------------------------------------------
+    def _admit(self, free: List[int]) -> Tuple[int, Request]:
+        with obs.span("serve.admit") as sp:
+            req, slot = self.queue.pop(0), free[0]
+            sp.set(req=req.req_id, slot=slot)
+            if req.submitted_at:        # set by submit(), not by a bare append
+                sp.set(queued_s=time.perf_counter() - req.submitted_at)
+        return slot, req
+
     def _insert(self, slot: int, req: Request):
-        p = np.zeros(self.prefill_len, np.int32)
-        n = min(len(req.prompt), self.prefill_len)
-        p[:n] = req.prompt[:n]
-        batch = {"tokens": jnp.asarray(p)[None]}
-        if self.cfg.family == "encdec":
-            batch["frames"] = jnp.zeros((1, self.cfg.n_frames,
-                                         self.cfg.d_model), jnp.float32)
-        if self.cfg.n_patches:
-            batch["patches"] = jnp.zeros((1, self.cfg.n_patches,
-                                          self.cfg.d_model), jnp.float32)
-        pre_cache = self.model.init_cache(1, self.max_seq)
-        logits, pre_cache, _ = self._prefill(self.model_params, batch,
-                                             pre_cache)
-        # copy row 0 of the single-row cache into the decode slot
-        def put(dst, src, key):
-            if key == "length":
-                return dst.at[slot].set(src[0])
-            return dst.at[:, slot].set(src[:, 0])
-        self.cache = {k: put(self.cache[k], pre_cache[k], k)
-                      for k in self.cache}
-        tok = greedy(logits)
-        self.last_token = self.last_token.at[slot].set(tok[0])
+        with obs.span("serve.prefill", req=req.req_id):
+            p = np.zeros(self.prefill_len, np.int32)
+            n = min(len(req.prompt), self.prefill_len)
+            p[:n] = req.prompt[:n]
+            batch = {"tokens": jnp.asarray(p)[None]}
+            if self.cfg.family == "encdec":
+                batch["frames"] = jnp.zeros((1, self.cfg.n_frames,
+                                             self.cfg.d_model), jnp.float32)
+            if self.cfg.n_patches:
+                batch["patches"] = jnp.zeros((1, self.cfg.n_patches,
+                                              self.cfg.d_model), jnp.float32)
+            pre_cache = self.model.init_cache(1, self.max_seq)
+            logits, pre_cache, _ = self._prefill(self.model_params, batch,
+                                                 pre_cache)
+        with obs.span("serve.insert", req=req.req_id, slot=slot):
+            # copy row 0 of the single-row cache into the decode slot
+            def put(dst, src, key):
+                if key == "length":
+                    return dst.at[slot].set(src[0])
+                return dst.at[:, slot].set(src[:, 0])
+            self.cache = {k: put(self.cache[k], pre_cache[k], k)
+                          for k in self.cache}
+            tok = greedy(logits)
+            self.last_token = self.last_token.at[slot].set(tok[0])
+        with obs.span("serve.read_first", req=req.req_id):
+            first = int(tok[0, 0])
         self.active[slot] = True
         self.remaining[slot] = req.max_new_tokens
-        req.output = [int(tok[0, 0])]
+        req.output = [first]
         self.slot_req[slot] = req
-        if self.builder is not None:
-            self.builder.add_step(kind="prefill")
-        self.kinds_log.append("prefill")
-        self.iterations += 1
+        self._log_step("prefill")
         obs.metrics().count("serve.prefill_iters")
 
     def _decode_all(self):
-        self.rng, sub = jax.random.split(self.rng)
-        logits, self.cache, _ = self._decode(self.model_params,
-                                             self.last_token, self.cache)
-        if self.temperature > 0:
-            tok = sample(logits, sub, temperature=self.temperature)
-        else:
-            tok = greedy(logits)
-        self.last_token = tok
-        toks = np.asarray(tok)[:, 0]
-        for b in range(self.batch):
-            if not self.active[b]:
-                continue
-            req = self.slot_req[b]
-            req.output.append(int(toks[b]))
-            self.remaining[b] -= 1
-            if (self.remaining[b] <= 0
-                    or int(self.cache["length"][b]) >= self.max_seq - 1):
-                req.finished_at = time.perf_counter()
-                self.done.append(req)
-                self.active[b] = False
-                self.slot_req[b] = None
-        if self.builder is not None:
-            self.builder.add_step(kind="decode")
-        self.kinds_log.append("decode")
-        self.iterations += 1
+        with obs.span("serve.decode", batch=int(self.active.sum())):
+            self.rng, sub = jax.random.split(self.rng)
+            logits, self.cache, _ = self._decode(self.model_params,
+                                                 self.last_token, self.cache)
+            if self.temperature > 0:
+                tok = sample(logits, sub, temperature=self.temperature)
+            else:
+                tok = greedy(logits)
+            self.last_token = tok
+        with obs.span("serve.read_tokens"):
+            toks = np.asarray(tok)[:, 0]
+        with obs.span("serve.retire") as sp:
+            done = []
+            for b in range(self.batch):
+                if not self.active[b]:
+                    continue
+                req = self.slot_req[b]
+                req.output.append(int(toks[b]))
+                self.remaining[b] -= 1
+                if (self.remaining[b] <= 0
+                        or self._read_length(b) >= self.max_seq - 1):
+                    req.finished_at = time.perf_counter()
+                    self.done.append(req)
+                    done.append(req.req_id)
+                    self.active[b] = False
+                    self.slot_req[b] = None
+            sp.set(done=done)
+        self._log_step("decode")
         obs.metrics().count("serve.decode_iters")
+
+    def _read_length(self, slot: int) -> int:
+        """Slot ``slot``'s cache length, read back from the device."""
+        with obs.span("serve.read_length", slot=slot):
+            return int(self.cache["length"][slot])
+
+    def _log_step(self, kind: str) -> None:
+        if self.builder is not None:
+            with obs.span("serve.meter"):
+                self.builder.add_step(kind=kind)
+        self.kinds_log.append(kind)
+        self.iterations += 1
 
     # ------------------------------------------------------------------
     def step(self, params) -> bool:
@@ -172,10 +198,12 @@ class ServeEngine:
         self.model_params = params
         free = [b for b in range(self.batch) if not self.active[b]]
         if free and self.queue:
-            self._insert(free[0], self.queue.pop(0))
+            with obs.span("serve.step", kind="insert"):
+                self._insert(*self._admit(free))
             return True
         if self.active.any():
-            self._decode_all()
+            with obs.span("serve.step", kind="decode"):
+                self._decode_all()
             return True
         return False
 

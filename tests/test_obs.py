@@ -32,6 +32,56 @@ def test_disabled_span_is_shared_noop():
     assert obs.tracer().events() == []
 
 
+def test_disabled_span_makes_no_profiler_call(monkeypatch):
+    """Disabled, a span is the shared no-op and never reaches jax; enabled,
+    it enters a profiler annotation of its name."""
+    from repro.obs import trace
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return None
+    monkeypatch.setattr(trace, "_annotation_type", lambda: Annotation)
+    assert Tracer(enabled=False).span("off") is obs.NULL_SPAN
+    with obs.span("off"):
+        pass
+    assert entered == []
+    with obs.configure(trace=True).span("on"):
+        pass
+    assert entered == ["on"]
+
+
+def test_enabled_spans_land_in_the_profiler_trace(tmp_path):
+    """Enabled spans appear, nested, on the host plane of a jax.profiler
+    trace (here on the CPU)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    t = obs.configure(trace=True)
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("obs_outer", stage="x"):
+            with t.span("obs_inner"):
+                jax.numpy.ones(4).block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("obs_"):
+                    found[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    assert set(found) == {"obs_outer", "obs_inner"}
+    (o0, o1), (i0, i1) = found["obs_outer"], found["obs_inner"]
+    assert o0 <= i0 < i1 <= o1
+    assert [e["name"] for e in t.events()] == ["obs_inner", "obs_outer"]
+
+
 def test_spans_nest_and_record_duration():
     t = obs.configure(trace=True)
     with t.span("outer", stage="profile") as outer:
