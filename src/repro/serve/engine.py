@@ -160,17 +160,19 @@ class ServeEngine:
                 tok = greedy(logits)
             self.last_token = tok
         with obs.span("serve.read_tokens"):
-            toks = np.asarray(tok)[:, 0]
+            # one blocking read of every slot's token and cache length, both
+            # outputs of this decode; the cache is read before the next
+            # decode donates it
+            toks, lens = jax.device_get((tok, self.cache["length"]))
         with obs.span("serve.retire") as sp:
             done = []
             for b in range(self.batch):
                 if not self.active[b]:
                     continue
                 req = self.slot_req[b]
-                req.output.append(int(toks[b]))
+                req.output.append(int(toks[b, 0]))
                 self.remaining[b] -= 1
-                if (self.remaining[b] <= 0
-                        or self._read_length(b) >= self.max_seq - 1):
+                if self.remaining[b] <= 0 or lens[b] >= self.max_seq - 1:
                     req.finished_at = time.perf_counter()
                     self.done.append(req)
                     done.append(req.req_id)
@@ -179,11 +181,6 @@ class ServeEngine:
             sp.set(done=done)
         self._log_step("decode")
         obs.metrics().count("serve.decode_iters")
-
-    def _read_length(self, slot: int) -> int:
-        """Slot ``slot``'s cache length, read back from the device."""
-        with obs.span("serve.read_length", slot=slot):
-            return int(self.cache["length"][slot])
 
     def _log_step(self, kind: str) -> None:
         if self.builder is not None:

@@ -1,9 +1,11 @@
 """Serving engine: continuous batching, determinism, snapshot/restore,
-heterogeneous profiling."""
+heterogeneous profiling, the cache-length cap, one device read per
+decode."""
 import jax
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_config, reduced
 from repro.models.model_zoo import build_model
 from repro.serve import Request, ServeEngine, SyntheticRequests
@@ -58,6 +60,65 @@ def test_profile_mixes_kinds(setup):
     names = prof.table.names
     assert any(n.startswith("prefill/") for n in names)
     assert any(n.startswith("decode/") for n in names)
+
+
+def test_length_cap_retires_at_last_cache_position(setup):
+    """A request whose budget runs past the cache retires on the decode that
+    brings its slot's cache length to ``max_seq - 1``; one with a small
+    budget still retires on its budget."""
+    cfg, m, params = setup
+    max_seq, prefill_len = 32, 8
+    eng = ServeEngine(cfg, batch=2, max_seq=max_seq, prefill_len=prefill_len,
+                      instrument=False)
+    gen = SyntheticRequests(cfg.vocab_size, prompt_len=8, seed=3)
+    long_req, short_req = gen.request(0), gen.request(1)
+    long_req.max_new_tokens = 100           # past max_seq - 1 - prefill_len
+    short_req.max_new_tokens = 5
+    eng.submit(long_req)
+    eng.submit(short_req)
+    lens = []                               # slot 0's length while it serves
+    while eng.step(params):
+        if eng.slot_req[0] is long_req or long_req.finished_at:
+            lens.append(int(np.asarray(eng.cache["length"])[0]))
+        if long_req.finished_at:
+            break
+    assert lens[-1] == max_seq - 1
+    assert max(lens[:-1]) < max_seq - 1
+    assert len(long_req.output) == max_seq - prefill_len
+    eng.run(params, [])
+    assert len(short_req.output) == 1 + short_req.max_new_tokens
+    assert sorted(r.req_id for r in eng.done) == [0, 1]
+
+
+def test_one_device_read_per_decode_iteration(setup):
+    """With tracing on, each decode iteration makes exactly one blocking
+    read, of every slot's token and cache length, and serves the same
+    tokens as with tracing off."""
+    cfg, m, params = setup
+    eng = ServeEngine(cfg, batch=3, max_seq=48, prefill_len=8,
+                      instrument=False)
+    gen = SyntheticRequests(cfg.vocab_size, prompt_len=8, mean_new=8, seed=4)
+
+    def served():
+        eng.reset()
+        eng.run(params, [gen.request(i) for i in range(5)])
+        return {r.req_id: r.output for r in eng.done}
+
+    untraced = served()
+    t = obs.configure(trace=True)
+    try:
+        traced = served()
+        evs = [e for e in t.events() if e["ph"] == "X"]
+    finally:
+        obs.configure(trace=False)
+    assert traced == untraced and len(traced) == 5
+    steps = [e for e in evs if e["name"] == "serve.step"
+             and e["args"]["kind"] == "decode"]
+    assert steps
+    for s in steps:
+        reads = [e["name"] for e in evs if e["name"].startswith("serve.read_")
+                 and s["ts"] <= e["ts"] <= s["ts"] + s["dur"]]
+        assert reads == ["serve.read_tokens"]
 
 
 def test_snapshot_restore_resumes_identically(setup):
