@@ -5,8 +5,8 @@ import importlib
 from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401
-    ArchConfig, AttnConfig, MoEConfig, SSMConfig, ShapeConfig, SHAPES,
-    shapes_for, reduced, dtype_of,
+    ArchConfig, AttnConfig, MLAConfig, MoEConfig, SSMConfig, ShapeConfig,
+    SHAPES, shapes_for, reduced, dtype_of,
 )
 
 _MODULES = {
@@ -20,6 +20,7 @@ _MODULES = {
     "whisper-tiny": "whisper_tiny",
     "zamba2-1.2b": "zamba2_1_2b",
     "internvl2-76b": "internvl2_76b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

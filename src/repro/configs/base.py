@@ -22,6 +22,19 @@ class MoEConfig:
     capacity_factor: float = 1.25     # dense-dispatch capacity bound
     router_jitter: float = 0.0
     aux_loss_coef: float = 0.01
+    # mla_moe family (DeepSeek-V3 routing): gates are the chosen sigmoid
+    # scores, normalised over the chosen k, times ``routed_scale``
+    routed_scale: float = 1.0
+    # experts held on this chip: ``n_held`` experts from ``held_first``
+    # (0 = all ``n_experts``); the router keeps its ``n_experts`` outputs
+    n_held: int = 0
+    held_first: int = 0
+    d_shared: int = 0                 # shared-expert width (0 = d_ff)
+
+    @property
+    def n_local(self) -> int:
+        """Experts whose weights live here."""
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,6 +45,17 @@ class SSMConfig:
     d_conv: int = 4
     chunk: int = 256                  # SSD chunk length (MXU-aligned)
     a_init_range: Tuple[float, float] = (1.0, 16.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values come
+    from a ``kv_lora_rank``-wide latent and one ``rope_dim``-wide rotary key
+    shared by all heads; ``AttnConfig.head_dim`` is the no-rope part of a
+    query/key head."""
+    kv_lora_rank: int
+    rope_dim: int
+    v_head_dim: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -47,12 +71,14 @@ class AttnConfig:
     local_window: int = 0             # 0 => all layers global
     global_every: int = 0             # every k-th layer is global (1-indexed)
     softcap: float = 0.0              # logit soft-capping (gemma-style), 0=off
+    mla: Optional[MLAConfig] = None   # latent attention (family mla_moe)
 
 
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                       # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                       # dense | moe | mla_moe | ssm | hybrid
+                                      # | encdec | vlm
     n_layers: int
     d_model: int
     d_ff: int
@@ -60,6 +86,9 @@ class ArchConfig:
     attn: Optional[AttnConfig] = None
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    # mla_moe: the first ``first_k_dense`` layers have a SwiGLU of width
+    # ``d_ff`` in place of the expert layer
+    first_k_dense: int = 0
     # hybrid (zamba2): one SHARED attention block applied every `attn_every`
     # SSM layers (params reused across applications, paper-faithful to the
     # released model family).
@@ -230,11 +259,17 @@ def reduced(cfg: ArchConfig, *, n_layers: int = 2, d_model: int = 64,
         changes["attn"] = dataclasses.replace(
             a, n_heads=nh, n_kv_heads=nkv, head_dim=16,
             local_window=min(a.local_window, 16) if a.local_window else 0)
+    if cfg.attn is not None and cfg.attn.mla is not None:
+        changes["attn"] = dataclasses.replace(
+            changes["attn"], mla=MLAConfig(kv_lora_rank=32, rope_dim=8,
+                                           v_head_dim=16))
     if cfg.moe is not None:
         m = cfg.moe
+        n = min(m.n_experts, 4)
         changes["moe"] = dataclasses.replace(
-            m, n_experts=min(m.n_experts, 4), top_k=min(m.top_k, 2),
-            d_expert=32)
+            m, n_experts=n, top_k=min(m.top_k, 2), d_expert=32,
+            n_held=min(m.n_held, n), held_first=0,
+            d_shared=32 * m.n_shared_experts if m.d_shared else 0)
     if cfg.ssm is not None:
         changes["ssm"] = dataclasses.replace(
             cfg.ssm, d_state=16, head_dim=16, chunk=16)

@@ -71,7 +71,25 @@ def build_block_table(model: Model, shape: ShapeConfig,
     prog.append(Segment((i_embed,), 1))
 
     # ---- per-layer blocks --------------------------------------------------
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family == "mla_moe":
+        from repro.models import moe as M
+        dense_lp = _spec_struct(T.mla_layer_specs(cfg, moe=False), dt)
+        c_attn = trace_cost(
+            lambda p, xx, pp: T._mla_attn_block(p, cfg, dims, xx, pp)[0],
+            lp, x, pos)
+        i_attn = add("mla", c_attn)
+        c_mlp = trace_cost(
+            lambda p, xx: T._mlp_block(p, cfg, xx, plus_one=False,
+                                       aux=T._aux_zero(cfg)), dense_lp, x)
+        i_mlp = add("mlp", c_mlp)
+        c_moe = trace_cost(
+            lambda p, xx: M.moe_held(p["moe"], cfg, xx)[0], lp, x)
+        i_moe = add("moe", c_moe)
+        prog.append(Segment((i_attn, i_mlp), cfg.first_k_dense))
+        prog.append(Segment((i_attn, i_moe),
+                            cfg.n_layers - cfg.first_k_dense))
+
+    elif cfg.family in ("dense", "moe", "vlm"):
         win = jnp.int32(-1)
         c_attn = trace_cost(
             lambda p, xx, pp: T._attn_block(p, cfg, dims, xx, pp, win,
@@ -155,7 +173,8 @@ def build_block_table(model: Model, shape: ShapeConfig,
     prog.append(Segment((i_head,), 1))
 
     # ---- virtual (signature-only) blocks -----------------------------------
-    if cfg.family == "moe":
+    # one entry per expert the router scores, held here or not
+    if cfg.family in ("moe", "mla_moe"):
         for e in range(cfg.moe.n_experts):
             add(f"expert_tok_{e}", IRCost(0, 0, 0), virtual=True,
                 dyn_key="expert_tokens", dyn_index=e)

@@ -1,4 +1,6 @@
-"""GQA attention: reference (quadratic), chunked (streaming softmax), pallas.
+"""GQA attention: reference (quadratic), chunked (streaming softmax), pallas;
+and multi-head latent attention (MLA), decompressed for prefill and
+absorbed for decode.
 
 TPU-mesh head padding
 ---------------------
@@ -203,7 +205,7 @@ def attend_chunked(q, k, v, q_pos, k_pos, layout: HeadLayout, *,
     FLOPs of the dense schedule).
     """
     b, sq, hp, hd = q.shape
-    sk = k.shape[1]
+    sk, hv = k.shape[1], v.shape[-1]            # hv: value head width
     qc = min(q_chunk, sq)
     kc = min(kv_chunk, sk)
     nq, nk = -(-sq // qc), -(-sk // kc)
@@ -219,7 +221,7 @@ def attend_chunked(q, k, v, q_pos, k_pos, layout: HeadLayout, *,
     g = layout.group
     n = hp // g
     kb = k.reshape(b, nk, kc, n, hd)
-    vb = v.reshape(b, nk, kc, n, hd)
+    vb = v.reshape(b, nk, kc, n, hv)
     kpb = k_pos.reshape(b, nk, kc)
 
     def q_block(qi, kv_hi):
@@ -244,13 +246,13 @@ def attend_chunked(q, k, v, q_pos, k_pos, layout: HeadLayout, *,
 
         m0 = jnp.full((b, n, g, qc), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((b, n, g, qc), jnp.float32)
-        a0 = jnp.zeros((b, n, g, qc, hd), jnp.float32)
+        a0 = jnp.zeros((b, n, g, qc, hv), jnp.float32)
         xs = (jnp.moveaxis(kb, 1, 0)[:kv_hi], jnp.moveaxis(vb, 1, 0)[:kv_hi],
               jnp.moveaxis(kpb, 1, 0)[:kv_hi])
         (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), xs)
         l = jnp.where(l == 0.0, 1.0, l)
         out = (acc / l[..., None])                  # [b,n,g,qc,hd]
-        return jnp.moveaxis(out, 3, 1).reshape(b, qc, hp, hd)
+        return jnp.moveaxis(out, 3, 1).reshape(b, qc, hp, hv)
 
     if causal_skip and causal:
         # unrolled q loop; inner scan only over kv blocks at/below the diagonal
@@ -298,3 +300,102 @@ def attend(impl: str, q, k, v, q_pos, k_pos, layout, *, causal, window,
                                     group=layout.group, causal=causal,
                                     window=window, cap=cap)
     raise ValueError(f"unknown attention impl {impl!r}")
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3, arXiv:2412.19437 §2.1.1)
+# ---------------------------------------------------------------------------
+#
+# q is a plain projection (no q latent) to per-head (nope | rope) parts;
+# ``wkv_a`` gives the latent c_kv, RMS-normed, and one rotary key k_pe shared
+# by all heads; ``wkv_b`` expands c_kv to per-head k_nope and v.  The cache
+# holds c_kv and k_pe only.  Prefill attends in the decompressed form;
+# decode folds ``wkv_b``'s key half into the query and its value half after
+# the weighted sum, so it reads the latent cache as it is.
+
+
+def mla_specs(a: AttnConfig, d: int) -> Dict[str, Any]:
+    m, h = a.mla, a.n_heads
+    return {
+        "wq": {"kernel": ParamSpec((d, h, a.head_dim + m.rope_dim),
+                                   ("embed", "heads", None), "normal")},
+        "wkv_a": {"kernel": ParamSpec((d, m.kv_lora_rank + m.rope_dim),
+                                      ("embed", None), "scaled")},
+        "kv_norm": {"scale": ParamSpec((m.kv_lora_rank,), (None,), "ones")},
+        "wkv_b": {"kernel": ParamSpec(
+            (m.kv_lora_rank, h, a.head_dim + m.v_head_dim),
+            (None, "heads", None), "scaled")},
+        "wo": {"kernel": ParamSpec((h, m.v_head_dim, d),
+                                   ("heads", None, "embed"), "scaled")},
+    }
+
+
+def mla_project(params, a: AttnConfig, x: jax.Array, positions: jax.Array,
+                dtype):
+    """x: [B,S,d] -> q_nope [B,S,H,nope], q_pe [B,S,H,rope], c_kv [B,S,rank]
+    (normed) and k_pe [B,S,rope]; RoPE (interleaved pairs) on the rope
+    parts."""
+    m = a.mla
+    x = x.astype(dtype)
+    q = jnp.einsum("bsd,dhk->bshk", x, L.get_kernel(params["wq"], dtype))
+    q_pe = L.apply_rope_interleaved(q[..., a.head_dim:], positions,
+                                    a.rope_theta)
+    kv = x @ L.get_kernel(params["wkv_a"], dtype)
+    c_kv = L.rmsnorm(params["kv_norm"], kv[..., :m.kv_lora_rank])
+    k_pe = L.apply_rope_interleaved(kv[..., None, m.kv_lora_rank:],
+                                    positions, a.rope_theta)[..., 0, :]
+    return q[..., :a.head_dim], q_pe, c_kv, k_pe
+
+
+def mla_out(params, ctx: jax.Array, dtype) -> jax.Array:
+    """ctx: [B,S,H,v] -> [B,S,d]."""
+    y = jnp.einsum("bshk,hkd->bsd", ctx.astype(dtype),
+                   L.get_kernel(params["wo"], dtype))
+    return shard(y, "batch", "seq", "act_embed")
+
+
+def mla_prefill(params, a: AttnConfig, layout: HeadLayout, q_nope, q_pe,
+                c_kv, k_pe, positions, dtype, *, impl: str = "chunked",
+                chunk: int = 1024) -> jax.Array:
+    """Causal attention in the decompressed form: per-head keys
+    ``[k_nope | k_pe]`` and values from ``wkv_b``; scale 1/sqrt(nope+rope).
+    Returns the heads' context [B,S,H,v]."""
+    if impl == "pallas":
+        raise NotImplementedError("MLA has no Pallas attention kernel")
+    kv = jnp.einsum("bsc,chk->bshk", c_kv.astype(dtype),
+                    L.get_kernel(params["wkv_b"], dtype))
+    k_nope, v = kv[..., :a.head_dim], kv[..., a.head_dim:]
+    b, s, h, _ = q_nope.shape
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None].astype(dtype),
+                                  (b, s, h, a.mla.rope_dim))], axis=-1)
+    return attend(impl, q, k, v, positions, positions, layout, causal=True,
+                  window=jnp.int32(-1), q_chunk=chunk, kv_chunk=chunk)
+
+
+def mla_decode(params, a: AttnConfig, q_nope, q_pe, c_cache, pe_cache,
+               cache_len, dtype) -> jax.Array:
+    """One query per row against the latent cache, absorbed: q_nope is
+    taken into the latent space by ``wkv_b``'s key half, scores are read
+    against c_kv and k_pe as cached, and the weighted sum of c_kv goes
+    through ``wkv_b``'s value half.  q_*: [B,1,H,*]; c_cache [B,S,rank],
+    pe_cache [B,S,rope]; positions below ``cache_len`` [B] are attended.
+    Returns the heads' context [B,1,H,v]."""
+    # float32 products, as attend_decode computes them (on the TPU, XLA
+    # feeds the cache's bf16 to the MXU as it is: no widened copy)
+    f32 = jnp.float32
+    w = L.get_kernel(params["wkv_b"], dtype).astype(f32)
+    w_uk, w_uv = w[..., :a.head_dim], w[..., a.head_dim:]
+    c_cache = c_cache.astype(f32)
+    q_lat = jnp.einsum("bshk,chk->bshc", q_nope.astype(f32), w_uk)
+    scores = (jnp.einsum("bshc,btc->bhst", q_lat, c_cache)
+              + jnp.einsum("bshr,btr->bhst", q_pe.astype(f32),
+                           pe_cache.astype(f32)))
+    scores = scores / math.sqrt(a.head_dim + a.mla.rope_dim)
+    t = jnp.arange(c_cache.shape[1], dtype=jnp.int32)
+    ok = t[None, :] < cache_len[:, None]                      # [B,S]
+    scores = jnp.where(ok[:, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum("bhst,btc->bshc", probs, c_cache)
+    return jnp.einsum("bshc,chk->bshk", ctx, w_uv).astype(dtype)

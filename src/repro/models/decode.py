@@ -21,8 +21,8 @@ from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import kvcache as KC
 from repro.models.transformer import (
-    ModelDims, _aux_zero, _hybrid_groups, _shared_attn_block, dense_layer,
-    embed_tokens, ssm_layer, unembed,
+    ModelDims, _aux_zero, _hybrid_groups, _mlp_block, _shared_attn_block,
+    dense_layer, embed_tokens, ssm_layer, unembed,
 )
 
 
@@ -78,7 +78,13 @@ def lm_prefill(params, cfg: ArchConfig, dims: ModelDims, tokens,
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     x = embed_tokens(params, cfg, dims, tokens, patch_embeds)
 
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family == "mla_moe":
+        x, aux, (c_kv, k_pe) = decoder_stack(params, cfg, dims, x, positions,
+                                             collect_kv=True)
+        for key, val in (("c_kv", c_kv), ("k_pe", k_pe)):  # [L,B,s,*]
+            cache[key] = jax.lax.dynamic_update_slice(
+                cache[key], val.astype(cache[key].dtype), (0, 0, 0, 0))
+    elif cfg.family in ("dense", "moe", "vlm"):
         x, aux, kv = decoder_stack(params, cfg, dims, x, positions,
                                    collect_kv=True, plus_one=plus_one)
         k, v = kv                                   # [L,B,s,KVp,hd]
@@ -185,7 +191,10 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
         raise NotImplementedError(
             "int8 KV cache is implemented for decoder-LM families")
 
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.family == "mla_moe":
+        x, aux = _mla_moe_decode(params, cfg, x, positions, lengths, cache,
+                                 aux)
+    elif cfg.family in ("dense", "moe", "vlm"):
         def body(carry, xs):
             xc, aux = carry
             if quant:
@@ -300,3 +309,40 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps, plus_one=plus_one)
     logits = unembed(params, cfg, dims, x)
     return logits, cache, aux
+
+
+def _mla_moe_decode(params, cfg: ArchConfig, x, positions, lengths,
+                    cache: Dict[str, Any], aux: Dict):
+    """One token per row through the dense then the expert layers, attention
+    absorbed over the latent cache.  The whole cache rides in the scans'
+    carry: each layer writes its row entries and reads its own slice, so no
+    layer's cache is copied out and back."""
+    rows = jnp.arange(x.shape[0])
+    dt = x.dtype
+
+    def body(carry, xs):
+        xc, aux, c_all, pe_all = carry
+        p, i = xs
+        aux = dict(aux)
+        with jax.named_scope("nugget_block_mla"):
+            h = L.rmsnorm(p["attn_norm"], xc, cfg.norm_eps)
+            q_nope, q_pe, c, pe = A.mla_project(p["attn"], cfg.attn, h,
+                                                positions, dt)
+            c_all = c_all.at[i, rows, lengths].set(c[:, 0].astype(
+                c_all.dtype))
+            pe_all = pe_all.at[i, rows, lengths].set(pe[:, 0].astype(
+                pe_all.dtype))
+            ctx = A.mla_decode(p["attn"], cfg.attn, q_nope, q_pe, c_all[i],
+                               pe_all[i], lengths + 1, dt)
+            xc = xc + A.mla_out(p["attn"], ctx, dt)
+        xc = _mlp_block(p, cfg, xc, plus_one=False, aux=aux)
+        return (xc, aux, c_all, pe_all), None
+
+    nd = cfg.first_k_dense
+    carry = (x, aux, cache["c_kv"], cache["k_pe"])
+    carry, _ = jax.lax.scan(body, carry, (params["dense_layers"],
+                                          jnp.arange(nd)))
+    carry, _ = jax.lax.scan(body, carry, (params["layers"],
+                                          jnp.arange(nd, cfg.n_layers)))
+    x, aux, cache["c_kv"], cache["k_pe"] = carry
+    return x, aux

@@ -1,7 +1,9 @@
 """KV cache (decoder self-attention) + recurrent SSM state.
 
 Layout: stacked over layers so the decode step scans layers with the cache as
-scan xs/ys.  ``k``/``v``: [L, B, S_max, KVp, hd]; SSM state: [L, B, nh, hd, N]
+scan xs/ys.  ``k``/``v``: [L, B, S_max, KVp, hd]; latent attention (MLA)
+holds no per-head keys or values but ``c_kv`` [L, B, S_max, rank] and
+``k_pe`` [L, B, S_max, rope]; SSM state: [L, B, nh, hd, N]
 and conv state [L, B, d_conv-1, d_conv_dim].  Sharding: batch over
 ("pod","data"), heads over "model"; for long-context (batch=1) the sequence
 dim is sharded over "data" instead (see ShardingPlan.kv_seq).
@@ -9,7 +11,7 @@ dim is sharded over "data" instead (see ShardingPlan.kv_seq).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +23,8 @@ CACHE_AXES = {
     "v": (None, "batch", "kv_seq", "act_heads", None),
     "k_scale": (None, "batch", "kv_seq", "act_heads"),
     "v_scale": (None, "batch", "kv_seq", "act_heads"),
+    "c_kv": (None, "batch", "kv_seq", None),
+    "k_pe": (None, "batch", "kv_seq", None),
     "cross_k": (None, "batch", None, "act_heads", None),
     "cross_v": (None, "batch", None, "act_heads", None),
     "ssm": (None, "batch", "act_heads", None, None),
@@ -46,10 +50,17 @@ def dequantize_kv(q: jax.Array, scale: jax.Array, dtype) -> jax.Array:
 
 def init_cache(n_layers: int, batch: int, max_seq: int, kv_pad: int,
                head_dim: int, dtype, *, ssm: Optional[Dict[str, int]] = None,
-               cross_len: int = 0, quant: bool = False) -> Dict[str, Any]:
+               cross_len: int = 0, quant: bool = False,
+               mla: Optional[Tuple[int, int]] = None) -> Dict[str, Any]:
+    """``mla``: (latent rank, rope width) of a latent-attention cache, which
+    then holds ``c_kv`` and ``k_pe`` in place of ``k`` and ``v``."""
     cache: Dict[str, Any] = {
         "length": jnp.zeros((batch,), jnp.int32),
     }
+    if mla is not None:
+        rank, rope = mla
+        cache["c_kv"] = jnp.zeros((n_layers, batch, max_seq, rank), dtype)
+        cache["k_pe"] = jnp.zeros((n_layers, batch, max_seq, rope), dtype)
     kv_dtype = jnp.int8 if quant else dtype
     if kv_pad:
         cache["k"] = jnp.zeros((n_layers, batch, max_seq, kv_pad, head_dim),

@@ -256,6 +256,23 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           theta: float) -> jax.Array:
+    """DeepSeek's convention: rotates the pairs ``(x[2i], x[2i+1])`` by
+    ``position · theta^(-2i/hd)``, in place.  (DeepSeek's modelling code
+    also moves the rotated pairs to halves; q and k move alike, so their
+    dot products, all that attention reads, are the same.)"""
+    hd = x.shape[-1]
+    freqs = rope_frequencies(hd, theta)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    angles = angles[..., None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], hd // 2, 2)
+    x1, x2 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def softcap(logits: jax.Array, cap: float) -> jax.Array:
     if cap <= 0:
         return logits

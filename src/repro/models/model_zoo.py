@@ -92,6 +92,10 @@ class Model:
                        d_conv=cfg.ssm.d_conv, conv_dim=S.conv_dim(cfg))
         if cfg.family == "ssm":
             return KC.init_cache(cfg.n_layers, batch, max_seq, 0, 0, dt, ssm=ssm)
+        if cfg.family == "mla_moe":
+            mla = cfg.attn.mla
+            return KC.init_cache(cfg.n_layers, batch, max_seq, 0, 0, dt,
+                                 mla=(mla.kv_lora_rank, mla.rope_dim))
         if cfg.family == "hybrid":
             ae, n_groups, _ = T._hybrid_groups(cfg)
             c = KC.init_cache(n_groups, batch, max_seq, kv_pad, hd, dt,

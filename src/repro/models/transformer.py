@@ -1,4 +1,5 @@
-"""Decoder-only LM covering dense / MoE / SSM / hybrid / VLM families.
+"""Decoder-only LM covering dense / MoE / MLA-MoE / SSM / hybrid / VLM
+families.
 
 Layers are *scanned* (params stacked on a leading "layer" axis) so the HLO
 stays compact for 88-layer archs and remat applies per-layer.  Per-layer
@@ -47,9 +48,27 @@ class ModelDims:
 # ---------------------------------------------------------------------------
 
 
+def mla_layer_specs(cfg: ArchConfig, *, moe: bool) -> Dict[str, Any]:
+    """A layer of the ``mla_moe`` family: latent attention, then the expert
+    layer (``moe``) or, in the leading dense layers, a SwiGLU of ``d_ff``."""
+    d = cfg.d_model
+    specs: Dict[str, Any] = {
+        "attn_norm": L.rmsnorm_specs(d),
+        "attn": A.mla_specs(cfg.attn, d),
+        "mlp_norm": L.rmsnorm_specs(d),
+    }
+    if moe:
+        specs["moe"] = M.moe_specs(cfg)
+    else:
+        specs["mlp"] = L.mlp_specs(d, cfg.d_ff, glu=cfg.glu)
+    return specs
+
+
 def layer_specs(cfg: ArchConfig, dims: ModelDims) -> Dict[str, Any]:
     d = cfg.d_model
     specs: Dict[str, Any] = {}
+    if cfg.family == "mla_moe":
+        return mla_layer_specs(cfg, moe=True)
     if cfg.family in ("dense", "moe", "vlm"):
         specs["attn_norm"] = L.rmsnorm_specs(d)
         specs["attn"] = A.attention_specs(cfg.attn, d, dims.layout)
@@ -81,7 +100,13 @@ def lm_specs(cfg: ArchConfig, dims: ModelDims) -> Dict[str, Any]:
         "final_norm": L.rmsnorm_specs(cfg.d_model),
     }
     per_layer = layer_specs(cfg, dims)
-    if cfg.scan_layers:
+    if cfg.family == "mla_moe":
+        # leading dense layers, then the expert layers, each a scanned stack
+        specs["dense_layers"] = L.stack_specs(
+            mla_layer_specs(cfg, moe=False), cfg.first_k_dense)
+        specs["layers"] = L.stack_specs(per_layer,
+                                        cfg.n_layers - cfg.first_k_dense)
+    elif cfg.scan_layers:
         specs["layers"] = L.stack_specs(per_layer, cfg.n_layers)
     else:
         specs["layers"] = {f"layer_{i}": per_layer for i in range(cfg.n_layers)}
@@ -118,12 +143,28 @@ def _attn_block(p, cfg: ArchConfig, dims: ModelDims, x, positions, window,
         return x + A.out_proj(p["attn"], dims.layout, ctx, dt), (k, v)
 
 
+def _mla_attn_block(p, cfg: ArchConfig, dims: ModelDims, x, positions):
+    """Latent attention over the whole sequence (decompressed); returns the
+    residual stream and the layer's (c_kv, k_pe) for the cache."""
+    with jax.named_scope("nugget_block_mla"):
+        h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps)
+        dt = x.dtype
+        q_nope, q_pe, c_kv, k_pe = A.mla_project(p["attn"], cfg.attn, h,
+                                                 positions, dt)
+        ctx = A.mla_prefill(p["attn"], cfg.attn, dims.layout, q_nope, q_pe,
+                            c_kv, k_pe, positions, dt,
+                            impl=cfg.attention_impl, chunk=cfg.attn_chunk)
+        return x + A.mla_out(p["attn"], ctx, dt), (c_kv, k_pe)
+
+
 def _mlp_block(p, cfg, x, *, plus_one: bool, aux: Dict, rng=None):
     scope = "nugget_block_moe" if "moe" in p else "nugget_block_mlp"
     with jax.named_scope(scope):
         h = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps, plus_one=plus_one)
         if "moe" in p:
-            y, moe_aux = M.moe_mlp(p["moe"], cfg, h, rng=rng)
+            y, moe_aux = (M.moe_held(p["moe"], cfg, h)
+                          if cfg.family == "mla_moe"
+                          else M.moe_mlp(p["moe"], cfg, h, rng=rng))
             for key, val in moe_aux.items():
                 aux[key] = aux.get(key, 0) + val
         else:
@@ -192,6 +233,10 @@ def _aux_zero(cfg: ArchConfig):
         aux["router_logits_max"] = jnp.zeros((), jnp.float32)
         aux["expert_tokens"] = jnp.zeros((cfg.moe.n_experts,), jnp.int32)
         aux["dropped_tokens"] = jnp.zeros((), jnp.int32)
+    if cfg.family == "mla_moe":
+        aux["expert_tokens"] = jnp.zeros((cfg.moe.n_experts,), jnp.int32)
+        aux["held_tokens"] = jnp.zeros((), jnp.int32)
+        aux["dropped_tokens"] = jnp.zeros((), jnp.int32)
     return aux
 
 
@@ -251,6 +296,10 @@ def decoder_stack(params, cfg: ArchConfig, dims: ModelDims, x, positions,
                   if collect_kv else None)
         return x, aux, kv
 
+    if cfg.family == "mla_moe":
+        return _mla_moe_stack(params, cfg, dims, x, positions,
+                              collect_kv=collect_kv)
+
     if cfg.family == "ssm":
         def body(carry, p):
             xc, aux = carry
@@ -264,6 +313,24 @@ def decoder_stack(params, cfg: ArchConfig, dims: ModelDims, x, positions,
         return _hybrid_stack(params, cfg, dims, x, positions,
                              collect_kv=collect_kv)
     raise ValueError(cfg.family)
+
+
+def _mla_moe_stack(params, cfg, dims, x, positions, *, collect_kv=False):
+    """The leading dense layers, then the expert layers, each stack a scan;
+    with ``collect_kv`` the (c_kv, k_pe) of every layer, stacked."""
+    def body(carry, p):
+        xc, aux = carry
+        aux = dict(aux)
+        xc, kv = _mla_attn_block(p, cfg, dims, xc, positions)
+        xc = _mlp_block(p, cfg, xc, plus_one=False, aux=aux)
+        return (xc, aux), (kv if collect_kv else None)
+    body = _maybe_remat(body, cfg)
+    (x, aux), kv_d = jax.lax.scan(body, (x, _aux_zero(cfg)),
+                                  params["dense_layers"])
+    (x, aux), kv_m = jax.lax.scan(body, (x, aux), params["layers"])
+    kv = (jax.tree.map(lambda a, b: jnp.concatenate([a, b]), kv_d, kv_m)
+          if collect_kv else None)
+    return x, aux, kv
 
 
 def _hybrid_groups(cfg: ArchConfig):

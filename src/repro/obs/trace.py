@@ -90,6 +90,10 @@ class Span:
 
     # -- span API ------------------------------------------------------
     def set(self, **attrs: Any) -> "Span":
+        """Attach attributes.  The emitted event holds this dict, so a value
+        known only after the span closed (a count read back from the
+        device) still reaches the buffer; a JSONL sink has the span as it
+        closed."""
         self.attrs.update(attrs)
         return self
 

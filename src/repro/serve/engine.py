@@ -8,7 +8,10 @@ serving intervals genuinely vary in composition — the serving analogue of the
 paper's multi-phase workloads.  ``snapshot()``/``restore()`` capture engine
 state for replay resets and elastic migration.  Each iteration is a
 ``serve.step`` span with a span per phase inside it (see
-``docs/observability.md``).
+``docs/observability.md``).  For an expert model the step's router counts
+come back in the step's one blocking read: they become attributes of the
+step's ``serve.prefill`` / ``serve.decode`` span, ``serve.*`` counters and
+the dynamic signature entries of the step's interval.
 """
 from __future__ import annotations
 
@@ -27,6 +30,17 @@ from repro.core.intervals import IntervalBuilder, Profile
 from repro.core.registry import BlockTable, merge_tables
 from repro.models.model_zoo import Model, build_model
 from repro.serve.sampler import greedy, sample
+
+
+# the router counts of an expert model's step (models.moe aux), read in the
+# step's one device-to-host transfer
+MOE_STATS = ("expert_tokens", "held_tokens", "dropped_tokens")
+# the entries the interval builder's virtual expert blocks read
+MOE_DYN = ("expert_tokens", "dropped_tokens")
+
+
+def moe_stats(aux: Dict[str, Any]) -> Dict[str, Any]:
+    return {k: aux[k] for k in MOE_STATS if k in aux}
 
 
 @dataclasses.dataclass
@@ -115,8 +129,8 @@ class ServeEngine:
                 sp.set(queued_s=time.perf_counter() - req.submitted_at)
         return slot, req
 
-    def _insert(self, slot: int, req: Request):
-        with obs.span("serve.prefill", req=req.req_id):
+    def _insert(self, params, slot: int, req: Request):
+        with obs.span("serve.prefill", req=req.req_id) as psp:
             p = np.zeros(self.prefill_len, np.int32)
             n = min(len(req.prompt), self.prefill_len)
             p[:n] = req.prompt[:n]
@@ -128,8 +142,7 @@ class ServeEngine:
                 batch["patches"] = jnp.zeros((1, self.cfg.n_patches,
                                               self.cfg.d_model), jnp.float32)
             pre_cache = self.model.init_cache(1, self.max_seq)
-            logits, pre_cache, _ = self._prefill(self.model_params, batch,
-                                                 pre_cache)
+            logits, pre_cache, aux = self._prefill(params, batch, pre_cache)
         with obs.span("serve.insert", req=req.req_id, slot=slot):
             # copy row 0 of the single-row cache into the decode slot
             def put(dst, src, key):
@@ -141,29 +154,30 @@ class ServeEngine:
             tok = greedy(logits)
             self.last_token = self.last_token.at[slot].set(tok[0])
         with obs.span("serve.read_first", req=req.req_id):
-            first = int(tok[0, 0])
+            first, moe = jax.device_get((tok[0, 0], moe_stats(aux)))
         self.active[slot] = True
         self.remaining[slot] = req.max_new_tokens
-        req.output = [first]
+        req.output = [int(first)]
         self.slot_req[slot] = req
-        self._log_step("prefill")
+        self._log_step("prefill", self._expert_load(psp, moe, "prefill"))
         obs.metrics().count("serve.prefill_iters")
 
-    def _decode_all(self):
-        with obs.span("serve.decode", batch=int(self.active.sum())):
+    def _decode_all(self, params):
+        with obs.span("serve.decode", batch=int(self.active.sum())) as dsp:
             self.rng, sub = jax.random.split(self.rng)
-            logits, self.cache, _ = self._decode(self.model_params,
-                                                 self.last_token, self.cache)
+            logits, self.cache, aux = self._decode(params, self.last_token,
+                                                   self.cache)
             if self.temperature > 0:
                 tok = sample(logits, sub, temperature=self.temperature)
             else:
                 tok = greedy(logits)
             self.last_token = tok
         with obs.span("serve.read_tokens"):
-            # one blocking read of every slot's token and cache length, both
-            # outputs of this decode; the cache is read before the next
-            # decode donates it
-            toks, lens = jax.device_get((tok, self.cache["length"]))
+            # one blocking read of every slot's token and cache length (and
+            # an expert model's router counts), all outputs of this decode;
+            # the cache is read before the next decode donates it
+            toks, lens, moe = jax.device_get(
+                (tok, self.cache["length"], moe_stats(aux)))
         with obs.span("serve.retire") as sp:
             done = []
             for b in range(self.batch):
@@ -172,35 +186,58 @@ class ServeEngine:
                 req = self.slot_req[b]
                 req.output.append(int(toks[b, 0]))
                 self.remaining[b] -= 1
-                if self.remaining[b] <= 0 or lens[b] >= self.max_seq - 1:
+                if self.remaining[b] <= 0 or lens[b] >= self.max_seq:
                     req.finished_at = time.perf_counter()
                     self.done.append(req)
                     done.append(req.req_id)
                     self.active[b] = False
                     self.slot_req[b] = None
             sp.set(done=done)
-        self._log_step("decode")
+        self._log_step("decode", self._expert_load(dsp, moe, "decode"))
         obs.metrics().count("serve.decode_iters")
 
-    def _log_step(self, kind: str) -> None:
+    def _expert_load(self, span, moe: Dict[str, Any],
+                     kind: str) -> Optional[Dict[str, Any]]:
+        """An expert model's router counts of one step (host arrays, summed
+        over its expert layers): ``held_tokens`` (token-expert pairs routed
+        to the experts held here) and ``expert_load_max`` (the busiest held
+        expert's pairs) go onto the step's ``serve.prefill`` /
+        ``serve.decode`` ``span``, which has closed by the time they are
+        read, and into the ``serve.*`` counters; returns the interval
+        builder's dynamic entries (None for a model without experts)."""
+        if "expert_tokens" not in moe:
+            return None
+        m = self.cfg.moe
+        load = moe["expert_tokens"][m.held_first:m.held_first + m.n_local]
+        held = int(moe.get("held_tokens", load.sum()))
+        load_max = int(load.max())
+        span.set(held_tokens=held, expert_load_max=load_max)
+        c = obs.metrics()
+        c.count(f"serve.held_tokens.{kind}", held)
+        c.count(f"serve.expert_load_max.{kind}", load_max)
+        c.count("serve.dropped_tokens", int(moe["dropped_tokens"]))
+        return {k: moe[k] for k in MOE_DYN}
+
+    def _log_step(self, kind: str,
+                  dyn: Optional[Dict[str, Any]] = None) -> None:
         if self.builder is not None:
             with obs.span("serve.meter"):
-                self.builder.add_step(kind=kind)
+                self.builder.add_step(kind=kind, dyn=dyn)
         self.kinds_log.append(kind)
         self.iterations += 1
 
     # ------------------------------------------------------------------
     def step(self, params) -> bool:
-        """One engine iteration.  Returns False when idle."""
-        self.model_params = params
+        """One engine iteration.  Returns False when idle.  The engine keeps
+        no reference to ``params`` after it returns."""
         free = [b for b in range(self.batch) if not self.active[b]]
         if free and self.queue:
             with obs.span("serve.step", kind="insert"):
-                self._insert(*self._admit(free))
+                self._insert(params, *self._admit(free))
             return True
         if self.active.any():
             with obs.span("serve.step", kind="decode"):
-                self._decode_all()
+                self._decode_all(params)
             return True
         return False
 

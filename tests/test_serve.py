@@ -64,15 +64,15 @@ def test_profile_mixes_kinds(setup):
 
 def test_length_cap_retires_at_last_cache_position(setup):
     """A request whose budget runs past the cache retires on the decode that
-    brings its slot's cache length to ``max_seq - 1``; one with a small
-    budget still retires on its budget."""
+    writes the cache's last position, bringing its slot's cache length to
+    ``max_seq``; one with a small budget still retires on its budget."""
     cfg, m, params = setup
     max_seq, prefill_len = 32, 8
     eng = ServeEngine(cfg, batch=2, max_seq=max_seq, prefill_len=prefill_len,
                       instrument=False)
     gen = SyntheticRequests(cfg.vocab_size, prompt_len=8, seed=3)
     long_req, short_req = gen.request(0), gen.request(1)
-    long_req.max_new_tokens = 100           # past max_seq - 1 - prefill_len
+    long_req.max_new_tokens = 100           # past max_seq - prefill_len
     short_req.max_new_tokens = 5
     eng.submit(long_req)
     eng.submit(short_req)
@@ -82,12 +82,30 @@ def test_length_cap_retires_at_last_cache_position(setup):
             lens.append(int(np.asarray(eng.cache["length"])[0]))
         if long_req.finished_at:
             break
-    assert lens[-1] == max_seq - 1
-    assert max(lens[:-1]) < max_seq - 1
-    assert len(long_req.output) == max_seq - prefill_len
+    assert lens[-1] == max_seq
+    assert max(lens[:-1]) < max_seq
+    assert len(long_req.output) == max_seq - prefill_len + 1
     eng.run(params, [])
     assert len(short_req.output) == 1 + short_req.max_new_tokens
     assert sorted(r.req_id for r in eng.done) == [0, 1]
+
+
+def test_last_cache_position_serves_the_same_tokens(setup):
+    """A request that fills its slot's cache to the last position is
+    served the tokens an engine with room to spare serves it."""
+    cfg, m, params = setup
+    max_seq, prefill_len = 24, 8
+    outs = []
+    for room in (max_seq, 2 * max_seq):
+        eng = ServeEngine(cfg, batch=2, max_seq=room,
+                          prefill_len=prefill_len, instrument=False)
+        req = SyntheticRequests(cfg.vocab_size, prompt_len=8,
+                                seed=5).request(0)
+        req.max_new_tokens = max_seq - prefill_len
+        eng.run(params, [req])
+        outs.append(req.output)
+    assert len(outs[0]) == max_seq - prefill_len + 1
+    assert outs[0] == outs[1]
 
 
 def test_one_device_read_per_decode_iteration(setup):
