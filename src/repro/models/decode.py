@@ -1,7 +1,8 @@
 """Prefill and single-token decode over stacked KV / SSM caches.
 
-Decode scans layers with the per-layer cache slice as scan xs and the updated
-slice as scan ys; cache writes are per-row scatters so continuous batching
+Attention decode carries the stacked cache through the layer scan and writes
+each row's new entry in place at [layer, row, length], so no layer's slice is
+copied out and back; cache writes are per-row scatters so continuous batching
 (per-row lengths) works.  For ``long_500k`` the cache sequence dim is sharded
 over "data" and the masked softmax in ``attend_decode`` auto-partitions into
 flash-decode partials (see DESIGN.md).
@@ -37,30 +38,31 @@ def _merge_conv(parts) -> jax.Array:
     return jnp.concatenate(parts, axis=-1)
 
 
-def _write_kv(k_l, v_l, k_new, v_new, lengths):
-    """Per-row scatter write of one token's kv at each row's length."""
-    b = k_l.shape[0]
-    rows = jnp.arange(b)
-    k_l = k_l.at[rows, lengths].set(k_new[:, 0].astype(k_l.dtype))
-    v_l = v_l.at[rows, lengths].set(v_new[:, 0].astype(v_l.dtype))
-    return (shard(k_l, "batch", "kv_seq", "act_heads", None),
-            shard(v_l, "batch", "kv_seq", "act_heads", None))
+def _write_kv(k_c, v_c, k_new, v_new, lengths, layer=None):
+    """Per-row scatter write of one token's kv at each row's length, into
+    one layer's cache [B,S,KVp,hd] or, given ``layer``, in place into the
+    stacked cache [L,B,S,KVp,hd]."""
+    at = (() if layer is None else (layer,)) + (
+        jnp.arange(k_new.shape[0]), lengths)
+    k_c = k_c.at[at].set(k_new[:, 0].astype(k_c.dtype))
+    v_c = v_c.at[at].set(v_new[:, 0].astype(v_c.dtype))
+    axes = KC.CACHE_AXES["k"][-k_c.ndim:]
+    return shard(k_c, *axes), shard(v_c, *axes)
 
 
-def _write_kv_quant(k_l, v_l, ks_l, vs_l, k_new, v_new, lengths):
-    """int8-cache variant: quantize the new token's kv per (row, head)."""
-    b = k_l.shape[0]
-    rows = jnp.arange(b)
+def _write_kv_quant(k_c, v_c, ks_c, vs_c, k_new, v_new, lengths, layer):
+    """int8-cache variant, in place into the stacked cache: quantize the new
+    token's kv per (row, head)."""
+    at = (layer, jnp.arange(k_new.shape[0]), lengths)
     kq, ks = KC.quantize_kv(k_new[:, 0])
     vq, vs = KC.quantize_kv(v_new[:, 0])
-    k_l = k_l.at[rows, lengths].set(kq)
-    v_l = v_l.at[rows, lengths].set(vq)
-    ks_l = ks_l.at[rows, lengths].set(ks)
-    vs_l = vs_l.at[rows, lengths].set(vs)
-    return (shard(k_l, "batch", "kv_seq", "act_heads", None),
-            shard(v_l, "batch", "kv_seq", "act_heads", None),
-            shard(ks_l, "batch", "kv_seq", "act_heads"),
-            shard(vs_l, "batch", "kv_seq", "act_heads"))
+    k_c = k_c.at[at].set(kq)
+    v_c = v_c.at[at].set(vq)
+    ks_c = ks_c.at[at].set(ks)
+    vs_c = vs_c.at[at].set(vs)
+    kv_axes, s_axes = KC.CACHE_AXES["k"], KC.CACHE_AXES["k_scale"]
+    return (shard(k_c, *kv_axes), shard(v_c, *kv_axes),
+            shard(ks_c, *s_axes), shard(vs_c, *s_axes))
 
 
 # ---------------------------------------------------------------------------
@@ -195,35 +197,31 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
         x, aux = _mla_moe_decode(params, cfg, x, positions, lengths, cache,
                                  aux)
     elif cfg.family in ("dense", "moe", "vlm"):
+        keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
+
         def body(carry, xs):
-            xc, aux = carry
-            if quant:
-                p, win, k_l, v_l, ks_l, vs_l = xs
-            else:
-                p, win, k_l, v_l = xs
+            xc, aux, kv = carry
+            p, win, i = xs
             aux = dict(aux)
             h = L.rmsnorm(p["attn_norm"], xc, cfg.norm_eps, plus_one=plus_one)
             dt = xc.dtype
             q, k, v = A.qkv(p["attn"], cfg.attn, dims.layout, h, positions, dt)
             if quant:
-                k_l, v_l, ks_l, vs_l = _write_kv_quant(
-                    k_l, v_l, ks_l, vs_l, k, v, lengths)
-                k_at = KC.dequantize_kv(k_l, ks_l, dt)
-                v_at = KC.dequantize_kv(v_l, vs_l, dt)
+                kv = _write_kv_quant(*kv, k, v, lengths, i)
+                k_at = KC.dequantize_kv(kv[0][i], kv[2][i], dt)
+                v_at = KC.dequantize_kv(kv[1][i], kv[3][i], dt)
             else:
-                k_l, v_l = _write_kv(k_l, v_l, k, v, lengths)
-                k_at, v_at = k_l, v_l
+                kv = _write_kv(*kv, k, v, lengths, i)
+                k_at, v_at = kv[0][i], kv[1][i]
             ctx = A.attend_decode(q, k_at, v_at, lengths + 1, dims.layout,
                                   window=win, cap=cfg.attn.softcap)
             attn_out = A.out_proj(p["attn"], dims.layout, ctx, dt)
-            from repro.models.transformer import _mlp_block
             if cfg.parallel_block:
                 # match the parallel-residual training math (one TP AR)
                 h2 = L.rmsnorm(p["mlp_norm"], xc, cfg.norm_eps,
                                plus_one=plus_one)
                 if "moe" in p:
-                    from repro.models import moe as MO
-                    y, moe_aux = MO.moe_mlp(p["moe"], cfg, h2)
+                    y, moe_aux = M.moe_mlp(p["moe"], cfg, h2)
                     for key, val in moe_aux.items():
                         aux[key] = aux.get(key, 0) + val
                 else:
@@ -232,21 +230,12 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
             else:
                 xc = xc + attn_out
                 xc = _mlp_block(p, cfg, xc, plus_one=plus_one, aux=aux)
-            if quant:
-                return (xc, aux), (k_l, v_l, ks_l, vs_l)
-            return (xc, aux), (k_l, v_l)
-        if quant:
-            (x, aux), (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-                body, (x, aux),
-                (params["layers"], windows, cache["k"], cache["v"],
-                 cache["k_scale"], cache["v_scale"]))
-            cache["k"], cache["v"] = k_new, v_new
-            cache["k_scale"], cache["v_scale"] = ks_new, vs_new
-        else:
-            (x, aux), (k_new, v_new) = jax.lax.scan(
-                body, (x, aux),
-                (params["layers"], windows, cache["k"], cache["v"]))
-            cache["k"], cache["v"] = k_new, v_new
+            return (xc, aux, kv), None
+
+        (x, aux, kv), _ = jax.lax.scan(
+            body, (x, aux, tuple(cache[key] for key in keys)),
+            (params["layers"], windows, jnp.arange(cfg.n_layers)))
+        cache.update(zip(keys, kv))
 
     elif cfg.family == "ssm":
         def body(carry, xs):

@@ -1,12 +1,12 @@
 """KV cache (decoder self-attention) + recurrent SSM state.
 
-Layout: stacked over layers so the decode step scans layers with the cache as
-scan xs/ys.  ``k``/``v``: [L, B, S_max, KVp, hd]; latent attention (MLA)
-holds no per-head keys or values but ``c_kv`` [L, B, S_max, rank] and
-``k_pe`` [L, B, S_max, rope]; SSM state: [L, B, nh, hd, N]
-and conv state [L, B, d_conv-1, d_conv_dim].  Sharding: batch over
-("pod","data"), heads over "model"; for long-context (batch=1) the sequence
-dim is sharded over "data" instead (see ShardingPlan.kv_seq).
+Layout: stacked over layers; decode carries the attention caches through its
+layer scan and scans the SSM state as scan xs/ys.  ``k``/``v``: [L, B,
+S_max, KVp, hd]; latent attention (MLA) holds no per-head keys or values but
+``c_kv`` [L, B, S_max, rank] and ``k_pe`` [L, B, S_max, rope]; SSM state:
+[L, B, nh, hd, N] and conv state [L, B, d_conv-1, d_conv_dim].  Sharding:
+batch over ("pod","data"), heads over "model"; for long-context (batch=1)
+the sequence dim is sharded over "data" instead (see ShardingPlan.kv_seq).
 """
 from __future__ import annotations
 

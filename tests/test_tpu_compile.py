@@ -2,12 +2,16 @@
 the widths the chip smoke runs: qwen3-1.7b attention (16 query / 8 kv
 heads of 128, sequence 2048, decode cache 4096) and mamba2-780m SSD (48
 heads of 64, state 128, chunk 256).  What Mosaic refuses here it would
-refuse on the chip.
+refuse on the chip.  The batched decode, compiled as the serving engine
+compiles it (cache donated), writes its cache in place: no cache-sized
+temporary, no copy of the stacked cache.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers import
 every test file."""
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +22,7 @@ from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ssd import ssd_intra
+from repro.models.model_zoo import build_model
 from repro.models.ssm import ssm_dims
 
 _QWEN3 = get_config("qwen3-1.7b").attn
@@ -83,3 +88,51 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# the qwen3-1.7b serving cell's decode batch: 32 slots of 768 positions
+_SLOTS, _MAX_SEQ = 32, 768
+# one layer's k slice of its bf16 cache (50.3 MB)
+_LAYER_SLICE = _SLOTS * _MAX_SEQ * _KV * _HD * 2
+# the cache arrays a decode reads whole; the int8 cache's per-(token, head)
+# scales, 8 wide, are relaid out where the program starts and ends
+_STACKED = ("k", "v", "c_kv", "k_pe")
+_HLO_DTYPE = {"bfloat16": "bf16", "int8": "s8"}
+
+
+def _decode_cfg(case):
+    """Two layers at the published widths.  ``mla_moe`` is the control,
+    whose decode always carried its cache: Moonlight's leading dense layer
+    and one expert layer."""
+    if case == "mla_moe":
+        return dataclasses.replace(get_config("moonlight-16b-a3b"),
+                                   n_layers=2)
+    cfg = dataclasses.replace(get_config("qwen3-1.7b"), n_layers=2)
+    if case == "int8_cache":
+        cfg = dataclasses.replace(cfg, cache_quant="int8")
+    return cfg
+
+
+@pytest.mark.parametrize("case", ["bf16_cache", "int8_cache", "mla_moe"])
+def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
+                                              no_persistent_cache):
+    m = build_model(_decode_cfg(case))
+    on_chip = lambda t: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        t)
+    params = on_chip(jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: m.init_cache(_SLOTS, _MAX_SEQ)))
+    token = jax.ShapeDtypeStruct((_SLOTS, 1), i32, sharding=one_chip)
+    compiled = jax.jit(m.decode_step, donate_argnums=(2,)).lower(
+        params, token, cache).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < _LAYER_SLICE
+    # no copy in device memory (HBM) of a whole cache array; one into the
+    # core's fast memory (layout tagged S(1)) stages a slice
+    text = compiled.as_text()
+    for name in [n for n in _STACKED if n in cache]:
+        a = cache[name]
+        shape = f"{_HLO_DTYPE[a.dtype.name]}[{','.join(map(str, a.shape))}]"
+        copies = [layout for layout in re.findall(
+            re.escape(shape) + r"\{([^}]*)\} copy\(", text)
+            if "S(" not in layout]
+        assert not copies, (name, copies)
