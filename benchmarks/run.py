@@ -16,8 +16,7 @@ import traceback
 from benchmarks import (bench_hook_overhead, bench_interval_overhead,
                         bench_kernels, bench_model_accuracy,
                         bench_pipeline, bench_prediction_error,
-                        bench_roofline, bench_speedup_prediction,
-                        bench_sync_scaling)
+                        bench_speedup_prediction, bench_sync_scaling)
 from benchmarks.common import fmt_rows
 
 SUITES = [
@@ -28,7 +27,6 @@ SUITES = [
     ("speedup_prediction(Fig7-10)", bench_speedup_prediction),
     ("model_accuracy(Fig11)", bench_model_accuracy),
     ("kernels", bench_kernels),
-    ("roofline", bench_roofline),
     ("pipeline(manifest)", bench_pipeline),
 ]
 

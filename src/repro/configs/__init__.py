@@ -6,7 +6,7 @@ from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401
     ArchConfig, AttnConfig, MLAConfig, MoEConfig, SSMConfig, ShapeConfig,
-    SHAPES, shapes_for, reduced, dtype_of,
+    reduced, dtype_of,
 )
 
 _MODULES = {

@@ -1,14 +1,14 @@
-"""Architecture / shape / run configuration dataclasses.
+"""Architecture and shape configuration dataclasses.
 
-Every assigned architecture gets a module in ``repro.configs`` exporting a
-single ``CONFIG: ArchConfig``.  Shapes are the four assignment-wide workload
-shapes; each config declares which shapes apply to it (``long_500k`` is only
-valid for sub-quadratic-attention families, per DESIGN.md §Arch-applicability).
+Every architecture has a module in ``repro.configs`` exporting a single
+``CONFIG: ArchConfig``; the benchmark builds its own from a published
+``config.json``.  A :class:`ShapeConfig` is the batch and sequence a block
+table is built for (a training step, a prefill or a decode).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -106,32 +106,15 @@ class ArchConfig:
     max_seq_len: int = 1 << 20
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
-    # implementation switches (perf levers; see EXPERIMENTS §Perf)
+    # implementation switches; platform tokens (pipeline.runtime) set the
+    # attention ones
     attention_impl: str = "chunked"   # reference | chunked | pallas
     ssm_impl: str = "chunked"         # reference | chunked | pallas
     attn_chunk: int = 1024            # KV chunk for streaming attention
-    attn_causal_skip: bool = False    # skip above-diagonal kv blocks (§Perf)
-    parallel_block: bool = False      # PaLM-style attn∥mlp (1 TP AR/layer)
-    remat_group: int = 1              # layers per remat/scan group (§Perf)
-    weight_quant: str = "none"        # none | int8 | int4 (weight-only, serving)
-    cache_quant: str = "none"         # none | int8 (KV cache, serving)
-    remat: str = "full"               # none | full | selective
-    scan_layers: bool = True
+    attn_causal_skip: bool = False    # skip above-diagonal kv blocks
     source: str = ""                  # provenance note [source; tier]
 
     # ---- derived ----------------------------------------------------------
-    @property
-    def is_subquadratic(self) -> bool:
-        if self.family in ("ssm", "hybrid"):
-            return True
-        if self.attn is not None and self.attn.local_window > 0:
-            return True                # sliding-window majority (gemma3)
-        return False
-
-    @property
-    def has_decoder(self) -> bool:
-        return True                    # all assigned archs decode (enc-dec incl.)
-
     def layer_windows(self) -> Tuple[int, ...]:
         """Static per-layer attention window (-1 == global) for the decoder."""
         a = self.attn
@@ -206,21 +189,6 @@ class ShapeConfig:
         if self.kind == "decode":
             return self.global_batch          # one new token per sequence
         return self.seq_len * self.global_batch
-
-
-SHAPES = {
-    "train_4k":    ShapeConfig("train_4k", "train", 4096, 256),
-    "prefill_32k": ShapeConfig("prefill_32k", "prefill", 32768, 32),
-    "decode_32k":  ShapeConfig("decode_32k", "decode", 32768, 128),
-    "long_500k":   ShapeConfig("long_500k", "decode", 524288, 1),
-}
-
-
-def shapes_for(cfg: ArchConfig) -> Sequence[ShapeConfig]:
-    out = [SHAPES["train_4k"], SHAPES["prefill_32k"], SHAPES["decode_32k"]]
-    if cfg.is_subquadratic:
-        out.append(SHAPES["long_500k"])
-    return out
 
 
 def dtype_of(name: str):

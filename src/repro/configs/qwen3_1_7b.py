@@ -1,4 +1,4 @@
-"""qwen3-1.7b — dense GQA with qk-norm.  [hf:Qwen/Qwen3-8B; hf]
+"""qwen3-1.7b — dense GQA with qk-norm.  [huggingface.co/Qwen/Qwen3-1.7B; hf]
 28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936.
 """
 from repro.configs.base import ArchConfig, AttnConfig
@@ -14,5 +14,5 @@ CONFIG = ArchConfig(
                     rope_theta=1000000.0),
     tie_embeddings=True,
     norm_eps=1e-6,
-    source="[hf:Qwen/Qwen3-8B; hf]",
+    source="[huggingface.co/Qwen/Qwen3-1.7B; hf]",
 )

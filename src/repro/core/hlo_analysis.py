@@ -1,7 +1,6 @@
 """Compiled-HLO analysis: op histograms, collective traffic, marker labels.
 
-Three consumers:
-- the dry-run (collective bytes for the roofline's third term),
+Consumers:
 - the model-accuracy case study (paper §V-B: per-nugget compiled-op histogram
   vs portable-IR histogram localizes where the backend "microcodes"
   differently than the IR-level view assumes),
@@ -12,7 +11,7 @@ from __future__ import annotations
 
 import collections
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -75,7 +74,7 @@ def op_histogram(hlo_text: str) -> Dict[str, int]:
 
 
 def collective_stats(hlo_text: str) -> Dict[str, Dict[str, float]]:
-    """Per collective kind: op count + operand bytes (roofline 3rd term).
+    """Per collective kind: op count + operand bytes.
 
     Operand bytes are resolved through the def-site size map; if an operand
     is unknown (e.g. a parameter), the op's own result size is used as the
@@ -125,10 +124,6 @@ def collective_stats(hlo_text: str) -> Dict[str, Dict[str, float]]:
         stats[base]["count"] += 1
         stats[base]["bytes"] += float(total)
     return stats
-
-
-def total_collective_bytes(hlo_text: str) -> float:
-    return sum(v["bytes"] for v in collective_stats(hlo_text).values())
 
 
 def find_scope_labels(hlo_text: str, needle: str) -> List[str]:

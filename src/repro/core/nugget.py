@@ -4,7 +4,7 @@ markers, plus warmup region and extrapolation weight.
 Adaptation note (DESIGN.md §2): an XLA step is atomic, so replay runs whole
 steps and attributes marker-bounded wall time by UoW pro-rating of the two
 boundary steps; markers are exact in unit-of-work space.  In "simulation"
-(the dry-run/profiler) markers are located by HLO scope label with zero
+(the profiler) markers are located by HLO scope label with zero
 runtime overhead — the analogue of gem5 PC tracking.
 """
 from __future__ import annotations

@@ -2,8 +2,7 @@
 
 Model code annotates params and activations with *logical* axis names; the
 active :class:`ShardingPlan` maps those to mesh axes.  Rules differ between
-training (2D FSDP×TP) and serving (TP + batch- or sequence-sharded KV), and
-per-arch overrides can disable tensor parallelism for tiny models (whisper).
+training (2D FSDP×TP) and serving (TP, batch-sharded KV).
 """
 from __future__ import annotations
 
@@ -50,29 +49,20 @@ def _mk(rules: Dict[str, Any], tp_size: int, dp_axes, tp_axis) -> ShardingPlan:
     return ShardingPlan(tuple(rules.items()), tp_size, tuple(dp_axes), tp_axis)
 
 
-def logical_rules(mesh: Mesh, *, mode: str = "train",
-                  tp_enabled: bool = True,
-                  shard_seq: bool = False) -> ShardingPlan:
+def logical_rules(mesh: Mesh, *, mode: str = "train") -> ShardingPlan:
     """Build the sharding plan for a mesh.
 
     mode="train":  batch over (pod?,data); params 2D: FSDP("data") × TP("model").
-    mode="serve":  params TP only (replicated over data); batch over (pod?,data)
-                   unless ``shard_seq`` (long-context) — then KV seq over "data".
+    mode="serve":  params TP only (replicated over data); batch over (pod?,data).
     """
     names = mesh.axis_names
     pod = "pod" if "pod" in names else None
     data = "data" if "data" in names else None
     model = "model" if "model" in names else None
-    if not tp_enabled:
-        model = None
     batch_axes = tuple(a for a in (pod, data) if a)
-    if shard_seq:
-        # long-context decode: batch=1 — the "data" axis shards the KV
-        # sequence instead of the batch
-        batch_axes = ()
     batch = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
-    fsdp = data if mode in ("train", "serve_fsdp") and not shard_seq else None
-    tp_size = int(mesh.shape["model"]) if (model and "model" in names) else 1
+    fsdp = data if mode == "train" else None
+    tp_size = int(mesh.shape["model"]) if model else 1
 
     rules: Dict[str, Any] = {
         "batch": batch,
@@ -91,7 +81,7 @@ def logical_rules(mesh: Mesh, *, mode: str = "train",
         "act_embed": None,        # activation d_model dim
         "act_heads": model,       # activation head dim
         "act_vocab": model,       # logits vocab dim
-        "kv_seq": ("data" if (shard_seq and data) else None),
+        "kv_seq": None,
         "seq": None,
     }
     return _mk(rules, tp_size, batch_axes, model)
@@ -138,26 +128,6 @@ def spec_for(axes: Sequence[Optional[str]]) -> P:
     if plan is None:
         return P()
     return plan.spec(axes)
-
-
-def plan_for(mesh: Mesh, arch_name: str, mode: str, shape_name: str = "",
-             param_count: int = 0) -> ShardingPlan:
-    """Per-arch overrides:
-
-    - tiny models (whisper) skip TP entirely — replicating a 39 M-param model
-      beats paying collectives for 24-wide matmuls;
-    - long_500k shards the KV sequence over "data" (batch=1);
-    - big-arch serving turns on FSDP-style weight sharding over "data" when
-      bf16 params / tp_size would exceed ~half of v5e HBM (mistral-123B,
-      internvl-76B, llama4-scout served on 256 chips need 2D weight sharding).
-    """
-    tp_enabled = arch_name not in ("whisper-tiny",)
-    shard_seq = shape_name == "long_500k"
-    tp = int(mesh.shape.get("model", 1)) if tp_enabled else 1
-    if mode == "serve" and param_count * 2 / max(tp, 1) > 8e9:
-        mode = "serve_fsdp"
-    return logical_rules(mesh, mode=mode, tp_enabled=tp_enabled,
-                         shard_seq=shard_seq)
 
 
 def params_shardings(mesh: Mesh, plan: ShardingPlan, axes_tree) -> Any:

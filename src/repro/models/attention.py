@@ -9,9 +9,7 @@ have head counts not divisible by 16 (llama4/qwen2.5: 40 q heads, 8 kv heads).
 JAX rejects uneven input shardings, so the parameter layout pads q heads up to
 a multiple of the TP size (pad heads are zero-init and **masked out of the
 output**, keeping the math of the assigned arch exact) and expands kv heads by
-replication slots (Megatron-style replicated KV for tp > n_kv_heads).  The
-FLOP overhead of padding is visible in the roofline MODEL_FLOPS/HLO ratio and
-is one of the §Perf levers.
+replication slots (Megatron-style replicated KV for tp > n_kv_heads).
 """
 from __future__ import annotations
 
@@ -108,7 +106,7 @@ def attention_specs(a: AttnConfig, d: int, layout: HeadLayout) -> Dict[str, Any]
 
 
 def _proj(p, x, heads_axes, dtype):
-    y = jnp.einsum("bsd,dhk->bshk", x.astype(dtype), L.get_kernel(p, dtype))
+    y = jnp.einsum("bsd,dhk->bshk", x.astype(dtype), p["kernel"].astype(dtype))
     if "bias" in p:
         y = y + p["bias"].astype(dtype)
     return shard(y, *heads_axes)
@@ -141,7 +139,7 @@ def out_proj(params, layout: HeadLayout, ctx: jax.Array, dtype) -> jax.Array:
     mask = jnp.asarray(layout.head_mask(), dtype)
     ctx = ctx * mask[None, None, :, None]        # kill structural pad heads
     y = jnp.einsum("bshk,hkd->bsd", ctx.astype(dtype),
-                   L.get_kernel(params["wo"], dtype))
+                   params["wo"]["kernel"].astype(dtype))
     return shard(y, "batch", "seq", "act_embed")
 
 
@@ -337,10 +335,10 @@ def mla_project(params, a: AttnConfig, x: jax.Array, positions: jax.Array,
     parts."""
     m = a.mla
     x = x.astype(dtype)
-    q = jnp.einsum("bsd,dhk->bshk", x, L.get_kernel(params["wq"], dtype))
+    q = jnp.einsum("bsd,dhk->bshk", x, params["wq"]["kernel"].astype(dtype))
     q_pe = L.apply_rope_interleaved(q[..., a.head_dim:], positions,
                                     a.rope_theta)
-    kv = x @ L.get_kernel(params["wkv_a"], dtype)
+    kv = x @ params["wkv_a"]["kernel"].astype(dtype)
     c_kv = L.rmsnorm(params["kv_norm"], kv[..., :m.kv_lora_rank])
     k_pe = L.apply_rope_interleaved(kv[..., None, m.kv_lora_rank:],
                                     positions, a.rope_theta)[..., 0, :]
@@ -350,7 +348,7 @@ def mla_project(params, a: AttnConfig, x: jax.Array, positions: jax.Array,
 def mla_out(params, ctx: jax.Array, dtype) -> jax.Array:
     """ctx: [B,S,H,v] -> [B,S,d]."""
     y = jnp.einsum("bshk,hkd->bsd", ctx.astype(dtype),
-                   L.get_kernel(params["wo"], dtype))
+                   params["wo"]["kernel"].astype(dtype))
     return shard(y, "batch", "seq", "act_embed")
 
 
@@ -363,7 +361,7 @@ def mla_prefill(params, a: AttnConfig, layout: HeadLayout, q_nope, q_pe,
     if impl == "pallas":
         raise NotImplementedError("MLA has no Pallas attention kernel")
     kv = jnp.einsum("bsc,chk->bshk", c_kv.astype(dtype),
-                    L.get_kernel(params["wkv_b"], dtype))
+                    params["wkv_b"]["kernel"].astype(dtype))
     k_nope, v = kv[..., :a.head_dim], kv[..., a.head_dim:]
     b, s, h, _ = q_nope.shape
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
@@ -385,7 +383,7 @@ def mla_decode(params, a: AttnConfig, q_nope, q_pe, c_cache, pe_cache,
     # float32 products, as attend_decode computes them (on the TPU, XLA
     # feeds the cache's bf16 to the MXU as it is: no widened copy)
     f32 = jnp.float32
-    w = L.get_kernel(params["wkv_b"], dtype).astype(f32)
+    w = params["wkv_b"]["kernel"].astype(dtype).astype(f32)
     w_uk, w_uv = w[..., :a.head_dim], w[..., a.head_dim:]
     c_cache = c_cache.astype(f32)
     q_lat = jnp.einsum("bshk,chk->bshc", q_nope.astype(f32), w_uk)

@@ -3,27 +3,25 @@
 Attention decode carries the stacked cache through the layer scan and writes
 each row's new entry in place at [layer, row, length], so no layer's slice is
 copied out and back; cache writes are per-row scatters so continuous batching
-(per-row lengths) works.  For ``long_500k`` the cache sequence dim is sharded
-over "data" and the masked softmax in ``attend_decode`` auto-partitions into
-flash-decode partials (see DESIGN.md).
+(per-row lengths) works.  Prefill runs the full-sequence layer stack once and
+writes every layer's keys and values (or latent entries) into the cache.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, dtype_of
+from repro.configs.base import ArchConfig
 from repro.distributed.sharding import shard
 from repro.models import attention as A
 from repro.models import layers as L
-from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import kvcache as KC
 from repro.models.transformer import (
     ModelDims, _aux_zero, _hybrid_groups, _mlp_block, _shared_attn_block,
-    dense_layer, embed_tokens, ssm_layer, unembed,
+    embed_tokens, unembed,
 )
 
 
@@ -48,21 +46,6 @@ def _write_kv(k_c, v_c, k_new, v_new, lengths, layer=None):
     v_c = v_c.at[at].set(v_new[:, 0].astype(v_c.dtype))
     axes = KC.CACHE_AXES["k"][-k_c.ndim:]
     return shard(k_c, *axes), shard(v_c, *axes)
-
-
-def _write_kv_quant(k_c, v_c, ks_c, vs_c, k_new, v_new, lengths, layer):
-    """int8-cache variant, in place into the stacked cache: quantize the new
-    token's kv per (row, head)."""
-    at = (layer, jnp.arange(k_new.shape[0]), lengths)
-    kq, ks = KC.quantize_kv(k_new[:, 0])
-    vq, vs = KC.quantize_kv(v_new[:, 0])
-    k_c = k_c.at[at].set(kq)
-    v_c = v_c.at[at].set(vq)
-    ks_c = ks_c.at[at].set(ks)
-    vs_c = vs_c.at[at].set(vs)
-    kv_axes, s_axes = KC.CACHE_AXES["k"], KC.CACHE_AXES["k_scale"]
-    return (shard(k_c, *kv_axes), shard(v_c, *kv_axes),
-            shard(ks_c, *s_axes), shard(vs_c, *s_axes))
 
 
 # ---------------------------------------------------------------------------
@@ -90,22 +73,10 @@ def lm_prefill(params, cfg: ArchConfig, dims: ModelDims, tokens,
         x, aux, kv = decoder_stack(params, cfg, dims, x, positions,
                                    collect_kv=True, plus_one=plus_one)
         k, v = kv                                   # [L,B,s,KVp,hd]
-        if cfg.cache_quant == "int8":
-            kq, ks = KC.quantize_kv(k)
-            vq, vs = KC.quantize_kv(v)
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], kq, (0, 0, 0, 0, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], vq, (0, 0, 0, 0, 0))
-            cache["k_scale"] = jax.lax.dynamic_update_slice(
-                cache["k_scale"], ks, (0, 0, 0, 0))
-            cache["v_scale"] = jax.lax.dynamic_update_slice(
-                cache["v_scale"], vs, (0, 0, 0, 0))
-        else:
-            cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0, 0))
-            cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0, 0))
+        cache["k"] = jax.lax.dynamic_update_slice(
+            cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0, 0))
+        cache["v"] = jax.lax.dynamic_update_slice(
+            cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0, 0))
     elif cfg.family == "ssm":
         x, aux = _ssm_prefill(params, cfg, x, cache)
     elif cfg.family == "hybrid":
@@ -188,54 +159,28 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
     windows = jnp.asarray(cfg.layer_windows() or [0], jnp.int32)
     aux = _aux_zero(cfg)
 
-    quant = cfg.cache_quant == "int8"
-    if quant and cfg.family not in ("dense", "moe", "vlm"):
-        raise NotImplementedError(
-            "int8 KV cache is implemented for decoder-LM families")
-
     if cfg.family == "mla_moe":
         x, aux = _mla_moe_decode(params, cfg, x, positions, lengths, cache,
                                  aux)
     elif cfg.family in ("dense", "moe", "vlm"):
-        keys = ("k", "v", "k_scale", "v_scale") if quant else ("k", "v")
-
         def body(carry, xs):
-            xc, aux, kv = carry
+            xc, aux, k_all, v_all = carry
             p, win, i = xs
             aux = dict(aux)
             h = L.rmsnorm(p["attn_norm"], xc, cfg.norm_eps, plus_one=plus_one)
             dt = xc.dtype
             q, k, v = A.qkv(p["attn"], cfg.attn, dims.layout, h, positions, dt)
-            if quant:
-                kv = _write_kv_quant(*kv, k, v, lengths, i)
-                k_at = KC.dequantize_kv(kv[0][i], kv[2][i], dt)
-                v_at = KC.dequantize_kv(kv[1][i], kv[3][i], dt)
-            else:
-                kv = _write_kv(*kv, k, v, lengths, i)
-                k_at, v_at = kv[0][i], kv[1][i]
-            ctx = A.attend_decode(q, k_at, v_at, lengths + 1, dims.layout,
-                                  window=win, cap=cfg.attn.softcap)
-            attn_out = A.out_proj(p["attn"], dims.layout, ctx, dt)
-            if cfg.parallel_block:
-                # match the parallel-residual training math (one TP AR)
-                h2 = L.rmsnorm(p["mlp_norm"], xc, cfg.norm_eps,
-                               plus_one=plus_one)
-                if "moe" in p:
-                    y, moe_aux = M.moe_mlp(p["moe"], cfg, h2)
-                    for key, val in moe_aux.items():
-                        aux[key] = aux.get(key, 0) + val
-                else:
-                    y = L.mlp(p["mlp"], h2, cfg.act, dt)
-                xc = xc + (attn_out + y)
-            else:
-                xc = xc + attn_out
-                xc = _mlp_block(p, cfg, xc, plus_one=plus_one, aux=aux)
-            return (xc, aux, kv), None
+            k_all, v_all = _write_kv(k_all, v_all, k, v, lengths, i)
+            ctx = A.attend_decode(q, k_all[i], v_all[i], lengths + 1,
+                                  dims.layout, window=win,
+                                  cap=cfg.attn.softcap)
+            xc = xc + A.out_proj(p["attn"], dims.layout, ctx, dt)
+            xc = _mlp_block(p, cfg, xc, plus_one=plus_one, aux=aux)
+            return (xc, aux, k_all, v_all), None
 
-        (x, aux, kv), _ = jax.lax.scan(
-            body, (x, aux, tuple(cache[key] for key in keys)),
+        (x, aux, cache["k"], cache["v"]), _ = jax.lax.scan(
+            body, (x, aux, cache["k"], cache["v"]),
             (params["layers"], windows, jnp.arange(cfg.n_layers)))
-        cache.update(zip(keys, kv))
 
     elif cfg.family == "ssm":
         def body(carry, xs):

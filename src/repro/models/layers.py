@@ -14,8 +14,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, dtype_of
-
 Params = Dict[str, Any]
 Axes = Dict[str, Any]
 
@@ -31,11 +29,8 @@ class ParamSpec:
     init: str = "normal"          # normal | zeros | ones | scaled | custom
     scale: float = 1.0
     init_fn: Optional[Callable[[jax.Array, Tuple[int, ...]], jax.Array]] = None
-    dtype: Optional[str] = None   # override model param dtype (int8 quant)
 
     def instantiate(self, key: jax.Array, dtype) -> jax.Array:
-        if self.dtype is not None:
-            dtype = jnp.dtype(self.dtype)
         if self.init_fn is not None:
             return self.init_fn(key, self.shape).astype(dtype)
         if self.init == "zeros":
@@ -117,80 +112,13 @@ def dense_specs(d_in: int, d_out: int, axes: Tuple[Optional[str], ...],
     return out
 
 
-def get_kernel(params: Params, compute_dtype) -> jax.Array:
-    """Materialize a (possibly int8-quantized) kernel in compute dtype.
-
-    Weight-only quantization (serving): kernels stored as int8 with a
-    per-output-channel scale; dequantized on use (on TPU the cast happens
-    post-load, so HBM traffic is the int8 bytes)."""
-    if "kernel_q" in params:
-        q = params["kernel_q"].astype(compute_dtype)
-        return q * params["kernel_scale"].astype(compute_dtype)[None]
-    return params["kernel"].astype(compute_dtype)
-
-
 def dense(params: Params, x: jax.Array, compute_dtype=None) -> jax.Array:
     if compute_dtype is None:
         compute_dtype = x.dtype
-    k = get_kernel(params, compute_dtype)
-    y = x.astype(compute_dtype) @ k
+    y = x.astype(compute_dtype) @ params["kernel"].astype(compute_dtype)
     if "bias" in params:
         y = y + params["bias"].astype(y.dtype)
     return y
-
-
-def _quant_reduce_axis(axes: Tuple[Optional[str], ...]) -> int:
-    """Contraction (input) axis of a kernel: axis 0, or 1 when the kernel is
-    layer-stacked (leading "layer" axis from stack_specs)."""
-    return 1 if (axes and axes[0] == "layer") else 0
-
-
-def quantize_specs(specs, qdtype: str = "int8"):
-    """ParamSpec-tree transform: replace every ``kernel`` spec with an
-    int8/int4 payload + per-out-channel scale specs (same logical sharding,
-    scale inherits the kernel's non-contracting axes)."""
-    def walk(node):
-        if isinstance(node, dict):
-            out = {}
-            for k, v in node.items():
-                if k == "kernel" and isinstance(v, ParamSpec) \
-                        and len(v.shape) >= 2:
-                    r = _quant_reduce_axis(v.axes)
-                    out["kernel_q"] = dataclasses.replace(
-                        v, init="zeros", dtype=qdtype)
-                    out["kernel_scale"] = ParamSpec(
-                        v.shape[:r] + v.shape[r + 1:],
-                        v.axes[:r] + v.axes[r + 1:], "ones", dtype="float32")
-                else:
-                    out[k] = walk(v)
-            return out
-        return node
-    return walk(specs)
-
-
-def quantize_params(params, axes=None):
-    """Real int8 symmetric per-output-channel quantization of every kernel.
-    ``axes`` (the matching logical-axes tree) disambiguates layer-stacked
-    kernels; without it the contraction axis is assumed to be 0."""
-    def walk(node, anode):
-        if isinstance(node, dict):
-            out = {}
-            for k, v in node.items():
-                av = anode.get(k) if isinstance(anode, dict) else None
-                if k == "kernel" and hasattr(v, "ndim") and v.ndim >= 2:
-                    r = _quant_reduce_axis(av if av is not None else ())
-                    w = jnp.asarray(v, jnp.float32)
-                    scale = jnp.maximum(jnp.max(jnp.abs(w), axis=r),
-                                        1e-8) / 127.0
-                    q = jnp.clip(jnp.round(w / jnp.expand_dims(scale, r)),
-                                 -127, 127)
-                    out["kernel_q"] = q.astype(jnp.int8)
-                    out["kernel_scale"] = scale
-                else:
-                    out[k] = walk(v, av)
-            return out
-        return node
-    return walk(params, axes)
 
 
 def embed_specs(vocab: int, d: int) -> Dict[str, ParamSpec]:

@@ -1,19 +1,16 @@
-"""Unified model facade: build any assigned architecture, expose
-init / loss / forward / prefill / decode plus cache construction and
-ShapeDtypeStruct input specs for the dry-run.
+"""Unified model facade: build any architecture, expose init / loss /
+forward / prefill / decode plus cache construction.
 """
 from __future__ import annotations
 
 import dataclasses
-import functools
-import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, ShapeConfig, dtype_of
-from repro.distributed.sharding import ShardingPlan, shard
+from repro.configs.base import ArchConfig, dtype_of
+from repro.distributed.sharding import ShardingPlan
 from repro.models import decode as D
 from repro.models import encdec as ED
 from repro.models import kvcache as KC
@@ -47,12 +44,8 @@ class Model:
     # ---- params ----------------------------------------------------------
     def specs(self):
         if self.cfg.family == "encdec":
-            specs = ED.encdec_specs(self.cfg, self.dims)
-        else:
-            specs = T.lm_specs(self.cfg, self.dims)
-        if self.cfg.weight_quant in ("int8", "int4"):
-            specs = L.quantize_specs(specs, self.cfg.weight_quant)
-        return specs
+            return ED.encdec_specs(self.cfg, self.dims)
+        return T.lm_specs(self.cfg, self.dims)
 
     def init(self, key: jax.Array):
         return L.init_tree(key, self.specs(), dtype_of(self.cfg.param_dtype))
@@ -83,7 +76,6 @@ class Model:
         dt = dtype_of(cfg.compute_dtype)
         kv_pad = self.dims.layout.kv_pad if self.dims.layout else 0
         hd = cfg.attn.head_dim if cfg.attn else 0
-        quant = cfg.cache_quant == "int8"
         ssm = None
         if cfg.family in ("ssm", "hybrid"):
             d_inner, nh = S.ssm_dims(cfg)
@@ -98,14 +90,12 @@ class Model:
                                  mla=(mla.kv_lora_rank, mla.rope_dim))
         if cfg.family == "hybrid":
             ae, n_groups, _ = T._hybrid_groups(cfg)
-            c = KC.init_cache(n_groups, batch, max_seq, kv_pad, hd, dt,
-                              ssm=ssm, quant=quant)
-            return c
+            return KC.init_cache(n_groups, batch, max_seq, kv_pad, hd, dt,
+                                 ssm=ssm)
         if cfg.family == "encdec":
             return KC.init_cache(cfg.n_layers, batch, max_seq, kv_pad, hd, dt,
-                                 cross_len=cfg.n_frames, quant=quant)
-        return KC.init_cache(cfg.n_layers, batch, max_seq, kv_pad, hd, dt,
-                             quant=quant)
+                                 cross_len=cfg.n_frames)
+        return KC.init_cache(cfg.n_layers, batch, max_seq, kv_pad, hd, dt)
 
     def prefill(self, params, batch, cache):
         cfg = self.cfg
@@ -120,31 +110,6 @@ class Model:
         if cfg.family == "encdec":
             return ED.encdec_decode(params, cfg, self.dims, token, cache)
         return D.lm_decode(params, cfg, self.dims, token, cache)
-
-    # ---- dry-run specs ---------------------------------------------------
-    def input_specs(self, shape: ShapeConfig) -> Dict[str, Any]:
-        """ShapeDtypeStruct stand-ins for every model input (no allocation)."""
-        cfg = self.cfg
-        b = shape.global_batch
-        i32 = jnp.int32
-        dt = dtype_of(cfg.compute_dtype)
-        if shape.kind in ("train", "prefill"):
-            s = shape.seq_len
-            out = {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
-            if shape.kind == "train":
-                out["labels"] = jax.ShapeDtypeStruct((b, s), i32)
-            if cfg.family == "encdec":
-                out["frames"] = jax.ShapeDtypeStruct((b, cfg.n_frames, cfg.d_model), dt)
-            if cfg.n_patches:
-                out["patches"] = jax.ShapeDtypeStruct((b, cfg.n_patches, cfg.d_model), dt)
-            return out
-        # decode: one new token + cache of seq_len
-        return {"token": jax.ShapeDtypeStruct((b, 1), i32)}
-
-    def cache_specs_struct(self, shape: ShapeConfig) -> Dict[str, Any]:
-        cache = jax.eval_shape(lambda: self.init_cache(shape.global_batch,
-                                                       shape.seq_len))
-        return cache
 
     def param_count(self, params=None) -> int:
         if params is not None:

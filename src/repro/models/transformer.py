@@ -11,13 +11,11 @@ the released family).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs.base import ArchConfig, dtype_of
 from repro.distributed.sharding import shard
@@ -106,10 +104,8 @@ def lm_specs(cfg: ArchConfig, dims: ModelDims) -> Dict[str, Any]:
             mla_layer_specs(cfg, moe=False), cfg.first_k_dense)
         specs["layers"] = L.stack_specs(per_layer,
                                         cfg.n_layers - cfg.first_k_dense)
-    elif cfg.scan_layers:
-        specs["layers"] = L.stack_specs(per_layer, cfg.n_layers)
     else:
-        specs["layers"] = {f"layer_{i}": per_layer for i in range(cfg.n_layers)}
+        specs["layers"] = L.stack_specs(per_layer, cfg.n_layers)
     if cfg.family == "hybrid":
         specs["shared_attn"] = shared_attn_specs(cfg, dims)
     if not cfg.tie_embeddings:
@@ -128,9 +124,9 @@ def lm_specs(cfg: ArchConfig, dims: ModelDims) -> Dict[str, Any]:
 
 def _attn_block(p, cfg: ArchConfig, dims: ModelDims, x, positions, window,
                 *, plus_one: bool, aux: Dict):
-    # named_scope labels survive into HLO metadata: the dry-run/profiler
-    # locates markers by label with ZERO runtime overhead — the gem5
-    # PC-label tracking analogue (paper §III-D2, DESIGN.md §2)
+    # named_scope labels survive into HLO metadata: the profiler locates
+    # markers by label with ZERO runtime overhead — the gem5 PC-label
+    # tracking analogue (paper §III-D2)
     with jax.named_scope("nugget_block_attn"):
         h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps, plus_one=plus_one)
         dt = x.dtype
@@ -176,29 +172,6 @@ def _mlp_block(p, cfg, x, *, plus_one: bool, aux: Dict, rng=None):
 def dense_layer(p, cfg, dims, x, positions, window, *, plus_one=False,
                 aux=None, rng=None):
     aux = {} if aux is None else aux
-    if cfg.parallel_block:
-        # PaLM-style parallel residual: y = x + attn(n1(x)) + mlp(n2(x)).
-        # The two TP partial outputs are summed BEFORE the residual add, so
-        # XLA's all-reduce reassociation emits ONE all-reduce per layer
-        # instead of two (§Perf lever; halves TP collective bytes).
-        h = L.rmsnorm(p["attn_norm"], x, cfg.norm_eps, plus_one=plus_one)
-        dt = x.dtype
-        q, k, v = A.qkv(p["attn"], cfg.attn, dims.layout, h, positions, dt)
-        ctx = A.attend(cfg.attention_impl, q, k, v, positions, positions,
-                       dims.layout, causal=True, window=window,
-                       cap=cfg.attn.softcap, q_chunk=cfg.attn_chunk,
-                       kv_chunk=cfg.attn_chunk,
-                       causal_skip=cfg.attn_causal_skip)
-        attn_out = A.out_proj(p["attn"], dims.layout, ctx, dt)
-        h2 = L.rmsnorm(p["mlp_norm"], x, cfg.norm_eps, plus_one=plus_one)
-        if "moe" in p:
-            y, moe_aux = M.moe_mlp(p["moe"], cfg, h2, rng=rng)
-            for key, val in moe_aux.items():
-                aux[key] = aux.get(key, 0) + val
-        else:
-            y = L.mlp(p["mlp"], h2, cfg.act, dt)
-        x = x + (attn_out + y)
-        return shard(x, "batch", "seq", "act_embed"), (k, v), aux
     x, kv = _attn_block(p, cfg, dims, x, positions, window,
                         plus_one=plus_one, aux=aux)
     x = _mlp_block(p, cfg, x, plus_one=plus_one, aux=aux, rng=rng)
@@ -215,15 +188,6 @@ def ssm_layer(p, cfg, x, *, aux=None):
 # ---------------------------------------------------------------------------
 # Full-sequence forward (training / prefill)
 # ---------------------------------------------------------------------------
-
-
-def _maybe_remat(fn, cfg: ArchConfig):
-    if cfg.remat == "none":
-        return fn
-    if cfg.remat == "selective":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        return jax.checkpoint(fn, policy=policy)
-    return jax.checkpoint(fn)
 
 
 def _aux_zero(cfg: ArchConfig):
@@ -256,44 +220,8 @@ def decoder_stack(params, cfg: ArchConfig, dims: ModelDims, x, positions,
             return (xc, aux), (kv if collect_kv else None)
         keys = (jax.random.split(rng, cfg.n_layers) if rng is not None
                 else jnp.zeros((cfg.n_layers, 2), jnp.uint32))
-        g = cfg.remat_group
-        if cfg.scan_layers and g > 1 and cfg.n_layers % g == 0 \
-                and not collect_kv:
-            # remat GROUPS of g layers: the bwd stash holds one residual per
-            # group instead of per layer, letting the microbatch count (and
-            # with it the FSDP weight-regather traffic) drop by ~g (§Perf).
-            def gbody(carry, xs):
-                xc, aux = carry
-                ps, wins, ks = xs
-                for i in range(g):
-                    aux = dict(aux)
-                    xc, _, aux = dense_layer(
-                        jax.tree.map(lambda a: a[i], ps), cfg, dims, xc,
-                        positions, wins[i], plus_one=plus_one, aux=aux,
-                        rng=ks[i])
-                return (xc, aux), None
-            gbody = _maybe_remat(gbody, cfg)
-            grouped = jax.tree.map(
-                lambda a: a.reshape(cfg.n_layers // g, g, *a.shape[1:]),
-                params["layers"])
-            (x, aux), kv = jax.lax.scan(
-                gbody, (x, aux0),
-                (grouped, windows.reshape(-1, g), keys.reshape(-1, g, 2)))
-            return x, aux, None
-        body = _maybe_remat(body, cfg)
-        if cfg.scan_layers:
-            (x, aux), kv = jax.lax.scan(
-                body, (x, aux0), (params["layers"], windows, keys))
-        else:
-            kvs = []
-            aux = aux0
-            for i in range(cfg.n_layers):
-                (x, aux), kv_i = body((x, aux),
-                                      (params["layers"][f"layer_{i}"],
-                                       windows[i], keys[i]))
-                kvs.append(kv_i)
-            kv = (jax.tree.map(lambda *a: jnp.stack(a), *kvs)
-                  if collect_kv else None)
+        (x, aux), kv = jax.lax.scan(jax.checkpoint(body), (x, aux0),
+                                    (params["layers"], windows, keys))
         return x, aux, kv
 
     if cfg.family == "mla_moe":
@@ -305,8 +233,8 @@ def decoder_stack(params, cfg: ArchConfig, dims: ModelDims, x, positions,
             xc, aux = carry
             xc, aux = ssm_layer(p, cfg, xc, aux=dict(aux))
             return (xc, aux), None
-        body = _maybe_remat(body, cfg)
-        (x, aux), _ = jax.lax.scan(body, (x, aux0), params["layers"])
+        (x, aux), _ = jax.lax.scan(jax.checkpoint(body), (x, aux0),
+                                   params["layers"])
         return x, aux, None
 
     if cfg.family == "hybrid":
@@ -324,7 +252,7 @@ def _mla_moe_stack(params, cfg, dims, x, positions, *, collect_kv=False):
         xc, kv = _mla_attn_block(p, cfg, dims, xc, positions)
         xc = _mlp_block(p, cfg, xc, plus_one=False, aux=aux)
         return (xc, aux), (kv if collect_kv else None)
-    body = _maybe_remat(body, cfg)
+    body = jax.checkpoint(body)
     (x, aux), kv_d = jax.lax.scan(body, (x, _aux_zero(cfg)),
                                   params["dense_layers"])
     (x, aux), kv_m = jax.lax.scan(body, (x, aux), params["layers"])
@@ -368,7 +296,7 @@ def _hybrid_stack(params, cfg, dims, x, positions, *, collect_kv=False):
         xc = carry
         xc, _ = ssm_layer(p, cfg, xc)
         return xc, None
-    ssm_body = _maybe_remat(ssm_body, cfg)
+    ssm_body = jax.checkpoint(ssm_body)
 
     kvs = []
     for g in range(n_groups):

@@ -4,7 +4,7 @@ import sys
 import threading
 
 # NOTE: do NOT set --xla_force_host_platform_device_count here — smoke tests
-# and benches must see 1 device (the dry-run sets it in its own process).
+# and benches must see 1 device (tests that need more set it in a child).
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.dirname(__file__))   # for the _hyp shim
 

@@ -1,6 +1,6 @@
 """Zero-overhead marker tracking "in simulation" (paper §III-D2): block
-named_scope labels must survive into the compiled HLO so the dry-run/profiler
-can locate marker blocks by label (the gem5 PC-label analogue) without any
+named_scope labels must survive into the compiled HLO so the profiler can
+locate marker blocks by label (the gem5 PC-label analogue) without any
 runtime hooks."""
 import jax
 import jax.numpy as jnp

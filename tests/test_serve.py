@@ -1,6 +1,6 @@
 """Serving engine: continuous batching, determinism, snapshot/restore,
 heterogeneous profiling, the cache-length cap, one device read per
-decode."""
+decode, batched serving against serving alone."""
 import jax
 import numpy as np
 import pytest
@@ -137,6 +137,34 @@ def test_one_device_read_per_decode_iteration(setup):
         reads = [e["name"] for e in evs if e["name"].startswith("serve.read_")
                  and s["ts"] <= e["ts"] <= s["ts"] + s["dur"]]
         assert reads == ["serve.read_tokens"]
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "moonlight-16b-a3b"])
+def test_batched_serving_matches_serving_alone(arch):
+    """Requests served together, under continuous batching with slots
+    reused at other rows' lengths, get exactly the tokens each gets served
+    alone at batch 1: a row's in-place cache writes leave the other slots
+    alone."""
+    cfg = reduced(get_config(arch))
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    gen = SyntheticRequests(cfg.vocab_size, prompt_len=8, mean_new=6, seed=6)
+    n = 5
+
+    def served(eng, ids):
+        eng.reset()
+        eng.run(params, [gen.request(i) for i in ids])
+        return {r.req_id: r.output for r in eng.done}
+
+    together = ServeEngine(cfg, batch=2, max_seq=32, prefill_len=8,
+                           instrument=False)
+    alone = ServeEngine(cfg, batch=1, max_seq=32, prefill_len=8,
+                        instrument=False)
+    want = {}
+    for i in range(n):
+        want.update(served(alone, [i]))
+    got = served(together, range(n))
+    assert together.kinds_log.count("prefill") == n   # 5 inserts, 2 slots
+    assert got == want and len(got) == n
 
 
 def test_snapshot_restore_resumes_identically(setup):

@@ -1,7 +1,7 @@
 """Multi-device integration: REAL sharded training/serving on an 8-device
 host mesh (subprocess — the device count must be set before jax init).
 
-Covers what the dry-run can't: numerics of the 2D-sharded step match the
+Covers what a single-device test can't: numerics of the 2D-sharded step match the
 single-device step, the instrumented profile is identical (binary
 independence across meshes), and elastic restore works across mesh shapes.
 """

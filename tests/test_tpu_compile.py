@@ -90,48 +90,42 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in text
 
 
-# the qwen3-1.7b serving cell's decode batch: 32 slots of 768 positions
-_SLOTS, _MAX_SEQ = 32, 768
-# one layer's k slice of its bf16 cache (50.3 MB)
-_LAYER_SLICE = _SLOTS * _MAX_SEQ * _KV * _HD * 2
-# the cache arrays a decode reads whole; the int8 cache's per-(token, head)
-# scales, 8 wide, are relaid out where the program starts and ends
+# the cache arrays a decode reads whole
 _STACKED = ("k", "v", "c_kv", "k_pe")
-_HLO_DTYPE = {"bfloat16": "bf16", "int8": "s8"}
+# case -> (config, decode slots, positions a slot holds): two layers at the
+# published widths.  ``bf16_cache`` is the qwen3-1.7b serving cell's decode
+# batch, ``steady_shape`` the steady cell's (1824 positions, not a multiple
+# of 128); ``mla_moe`` is the control, whose decode always carried its
+# cache: Moonlight's leading dense layer and one expert layer.
+_DECODE = {
+    "bf16_cache": ("qwen3-1.7b", 32, 768),
+    "steady_shape": ("qwen3-1.7b", 16, 1824),
+    "mla_moe": ("moonlight-16b-a3b", 32, 768),
+}
 
 
-def _decode_cfg(case):
-    """Two layers at the published widths.  ``mla_moe`` is the control,
-    whose decode always carried its cache: Moonlight's leading dense layer
-    and one expert layer."""
-    if case == "mla_moe":
-        return dataclasses.replace(get_config("moonlight-16b-a3b"),
-                                   n_layers=2)
-    cfg = dataclasses.replace(get_config("qwen3-1.7b"), n_layers=2)
-    if case == "int8_cache":
-        cfg = dataclasses.replace(cfg, cache_quant="int8")
-    return cfg
-
-
-@pytest.mark.parametrize("case", ["bf16_cache", "int8_cache", "mla_moe"])
+@pytest.mark.parametrize("case", sorted(_DECODE))
 def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
                                               no_persistent_cache):
-    m = build_model(_decode_cfg(case))
+    arch, slots, max_seq = _DECODE[case]
+    m = build_model(dataclasses.replace(get_config(arch), n_layers=2))
     on_chip = lambda t: jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         t)
     params = on_chip(jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: m.init_cache(_SLOTS, _MAX_SEQ)))
-    token = jax.ShapeDtypeStruct((_SLOTS, 1), i32, sharding=one_chip)
+    cache = on_chip(jax.eval_shape(lambda: m.init_cache(slots, max_seq)))
+    token = jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip)
     compiled = jax.jit(m.decode_step, donate_argnums=(2,)).lower(
         params, token, cache).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < _LAYER_SLICE
+    # under one layer's k slice of qwen3's bf16 cache (50.3 MB at 32 x 768)
+    layer_slice = slots * max_seq * _KV * _HD * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
     # no copy in device memory (HBM) of a whole cache array; one into the
     # core's fast memory (layout tagged S(1)) stages a slice
     text = compiled.as_text()
     for name in [n for n in _STACKED if n in cache]:
         a = cache[name]
-        shape = f"{_HLO_DTYPE[a.dtype.name]}[{','.join(map(str, a.shape))}]"
+        shape = f"bf16[{','.join(map(str, a.shape))}]"
         copies = [layout for layout in re.findall(
             re.escape(shape) + r"\{([^}]*)\} copy\(", text)
             if "S(" not in layout]
