@@ -1,17 +1,19 @@
 """Serving engine: continuous batching over a fixed-shape decode batch.
 
-Requests prefill into a single-row cache (fixed prefill length, padded) and
-are inserted into a free decode slot; every engine iteration decodes the full
-batch (inactive slots masked).  The engine is a *profiled program*: prefill
-and decode iterations emit different hook streams (merged BlockTable), so
-serving intervals genuinely vary in composition — the serving analogue of the
-paper's multi-phase workloads.  ``snapshot()``/``restore()`` capture engine
-state for replay resets and elastic migration.  Each iteration is a
-``serve.step`` span with a span per phase inside it (see
-``docs/observability.md``).  For an expert model the step's router counts
-come back in the step's one blocking read: they become attributes of the
-step's ``serve.prefill`` / ``serve.decode`` span, ``serve.*`` counters and
-the dynamic signature entries of the step's interval.
+Requests prefill into a single-row cache (fixed prefill length, padded),
+built inside the prefill program, and are inserted into a free decode slot
+by one program that writes the row into the donated decode cache in place;
+every engine iteration decodes the full batch (inactive slots masked).  The
+engine is a *profiled program*: prefill and decode iterations emit different
+hook streams (merged BlockTable), so serving intervals genuinely vary in
+composition — the serving analogue of the paper's multi-phase workloads.
+``snapshot()``/``restore()`` capture engine state for replay resets and
+elastic migration.  Each iteration is a ``serve.step`` span with a span per
+phase inside it (see ``docs/observability.md``).  For an expert model the
+step's router counts come back in the step's one blocking read: they become
+attributes of the step's ``serve.prefill`` / ``serve.decode`` span,
+``serve.*`` counters and the dynamic signature entries of the step's
+interval.
 """
 from __future__ import annotations
 
@@ -22,6 +24,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 from repro.configs.base import ArchConfig, ShapeConfig
@@ -41,6 +44,18 @@ MOE_DYN = ("expert_tokens", "dropped_tokens")
 
 def moe_stats(aux: Dict[str, Any]) -> Dict[str, Any]:
     return {k: aux[k] for k in MOE_STATS if k in aux}
+
+
+def insert_slot(cache: Dict[str, jax.Array], row: Dict[str, jax.Array],
+                slot: jax.Array, last_token: jax.Array, tok: jax.Array):
+    """Write the single-row cache ``row`` into decode slot ``slot`` of
+    ``cache`` (stacked keys at axis 1, ``length`` at axis 0), and the first
+    token ``tok`` [1, 1] into ``last_token``'s slot.  Jitted with ``cache``
+    donated, the slot's rows are written in place."""
+    return ({k: lax.dynamic_update_index_in_dim(
+                cache[k], row[k], slot, 0 if k == "length" else 1)
+             for k in cache},
+            lax.dynamic_update_index_in_dim(last_token, tok, slot, 0))
 
 
 @dataclasses.dataclass
@@ -80,7 +95,8 @@ class ServeEngine:
         self.temperature = temperature
         self.rng = jax.random.PRNGKey(seed)
 
-        self._prefill = jax.jit(self.model.prefill)
+        self._prefill = jax.jit(self._prefill_row)
+        self._insert_slot = jax.jit(insert_slot, donate_argnums=(0, 3))
         self._decode = jax.jit(self.model.decode_step, donate_argnums=(2,))
 
         self.table: Optional[BlockTable] = None
@@ -129,6 +145,14 @@ class ServeEngine:
                 sp.set(queued_s=time.perf_counter() - req.submitted_at)
         return slot, req
 
+    def _prefill_row(self, params, batch):
+        """The prefill of one request into a single-row cache made here;
+        returns the logits, the row's cache, the aux and the greedy first
+        token [1, 1]."""
+        row = self.model.init_cache(1, self.max_seq)
+        logits, row, aux = self.model.prefill(params, batch, row)
+        return logits, row, aux, greedy(logits)
+
     def _insert(self, params, slot: int, req: Request):
         with obs.span("serve.prefill", req=req.req_id) as psp:
             p = np.zeros(self.prefill_len, np.int32)
@@ -141,23 +165,15 @@ class ServeEngine:
             if self.cfg.n_patches:
                 batch["patches"] = jnp.zeros((1, self.cfg.n_patches,
                                               self.cfg.d_model), jnp.float32)
-            pre_cache = self.model.init_cache(1, self.max_seq)
-            logits, pre_cache, aux = self._prefill(params, batch, pre_cache)
+            _, row, aux, tok = self._prefill(params, batch)
         with obs.span("serve.insert", req=req.req_id, slot=slot):
-            # copy row 0 of the single-row cache into the decode slot
-            def put(dst, src, key):
-                if key == "length":
-                    return dst.at[slot].set(src[0])
-                return dst.at[:, slot].set(src[:, 0])
-            self.cache = {k: put(self.cache[k], pre_cache[k], k)
-                          for k in self.cache}
-            tok = greedy(logits)
-            self.last_token = self.last_token.at[slot].set(tok[0])
+            self.cache, self.last_token = self._insert_slot(
+                self.cache, row, np.int32(slot), self.last_token, tok)
         with obs.span("serve.read_first", req=req.req_id):
-            first, moe = jax.device_get((tok[0, 0], moe_stats(aux)))
+            first, moe = jax.device_get((tok, moe_stats(aux)))
         self.active[slot] = True
         self.remaining[slot] = req.max_new_tokens
-        req.output = [int(first)]
+        req.output = [int(first[0, 0])]
         self.slot_req[slot] = req
         self._log_step("prefill", self._expert_load(psp, moe, "prefill"))
         obs.metrics().count("serve.prefill_iters")

@@ -197,3 +197,71 @@ def test_snapshot_restore_resumes_identically(setup):
     for _ in range(3):
         eng2.step(params)
     np.testing.assert_array_equal(after_direct, np.asarray(eng2.last_token))
+
+
+def _put(cache, row, slot):
+    """The eager rule the engine's insert replaced: row 0 of the
+    single-row cache into the decode slot, key by key."""
+    return {k: (cache[k].at[slot].set(row[k][0]) if k == "length"
+                else cache[k].at[:, slot].set(row[k][:, 0]))
+            for k in cache}
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b", "moonlight-16b-a3b",
+                                  "mamba2-780m"])
+def test_insert_writes_slot_in_place(arch):
+    """The insert writes the prefilled row and the first token into the
+    first and the last slot of a decode cache filled with noise: every
+    key comes out as the eager rule gives, and the decode cache and
+    ``last_token`` it was given are donated."""
+    cfg = reduced(get_config(arch))
+    eng = ServeEngine(cfg, batch=3, max_seq=24, prefill_len=8,
+                      instrument=False)
+    params = eng.model.init(jax.random.PRNGKey(0))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), len(eng.cache) + 1))
+    eng.cache = {k: (jax.random.randint(next(keys), v.shape, 0, 24, v.dtype)
+                     if k == "length"
+                     else jax.random.normal(next(keys), v.shape, v.dtype))
+                 for k, v in eng.cache.items()}
+    eng.last_token = jax.random.randint(next(keys), (3, 1), 0, 100,
+                                        np.int32)
+    seen = []
+    prefill = eng._prefill
+
+    def keep(*a):
+        seen.append(prefill(*a))
+        return seen[-1]
+    eng._prefill = keep
+    gen = SyntheticRequests(cfg.vocab_size, prompt_len=8, seed=7)
+    for slot in (0, eng.batch - 1):
+        # host copies: a view of a buffer would keep it from being donated
+        old = jax.tree.map(np.array, eng.cache)
+        old_tok = np.array(eng.last_token)
+        given = jax.tree.leaves((eng.cache, eng.last_token))
+        eng._insert(params, slot, gen.request(slot))
+        _, row, _, tok = seen[-1]
+        want = _put(jax.tree.map(jax.numpy.asarray, old), row, slot)
+        assert all(a.is_deleted() for a in given)
+        assert set(eng.cache) == set(want)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(eng.cache[k]),
+                                          np.asarray(want[k]), err_msg=k)
+        old_tok[slot] = np.asarray(tok)[0]
+        np.testing.assert_array_equal(np.asarray(eng.last_token), old_tok)
+        assert eng.slot_req[slot].output == [int(np.asarray(tok)[0, 0])]
+
+
+def test_insert_compiles_once_for_every_slot(setup):
+    """The slot is a traced index: inserts into every slot share one
+    compiled program.  Every engine's jit of ``insert_slot`` shares one
+    cache, so the count is of what this engine's shapes (used by no other
+    test) add to it."""
+    cfg, m, params = setup
+    eng = ServeEngine(cfg, batch=4, max_seq=40, prefill_len=8,
+                      instrument=False)
+    gen = SyntheticRequests(cfg.vocab_size, prompt_len=8, seed=8)
+    before = eng._insert_slot._cache_size()
+    for slot in range(eng.batch):
+        eng._insert(params, slot, gen.request(slot))
+    assert eng.active.all()
+    assert eng._insert_slot._cache_size() - before == 1

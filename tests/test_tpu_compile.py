@@ -2,9 +2,9 @@
 the widths the chip smoke runs: qwen3-1.7b attention (16 query / 8 kv
 heads of 128, sequence 2048, decode cache 4096) and mamba2-780m SSD (48
 heads of 64, state 128, chunk 256).  What Mosaic refuses here it would
-refuse on the chip.  The batched decode, compiled as the serving engine
-compiles it (cache donated), writes its cache in place: no cache-sized
-temporary, no copy of the stacked cache.
+refuse on the chip.  The batched decode and the slot insert, compiled as
+the serving engine compiles them (cache donated), write the cache in
+place: no cache-sized temporary, no copy of the stacked cache.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers import
@@ -24,6 +24,7 @@ from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ssd import ssd_intra
 from repro.models.model_zoo import build_model
 from repro.models.ssm import ssm_dims
+from repro.serve.engine import insert_slot
 
 _QWEN3 = get_config("qwen3-1.7b").attn
 _MAMBA2 = get_config("mamba2-780m")
@@ -104,19 +105,13 @@ _DECODE = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(_DECODE))
-def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
-                                              no_persistent_cache):
-    arch, slots, max_seq = _DECODE[case]
-    m = build_model(dataclasses.replace(get_config(arch), n_layers=2))
-    on_chip = lambda t: jax.tree.map(
+def _on_chip(tree, one_chip):
+    return jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-        t)
-    params = on_chip(jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: m.init_cache(slots, max_seq)))
-    token = jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip)
-    compiled = jax.jit(m.decode_step, donate_argnums=(2,)).lower(
-        params, token, cache).compile()
+        tree)
+
+
+def _assert_in_place(compiled, cache, slots, max_seq):
     # under one layer's k slice of qwen3's bf16 cache (50.3 MB at 32 x 768)
     layer_slice = slots * max_seq * _KV * _HD * 2
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
@@ -130,3 +125,40 @@ def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
             re.escape(shape) + r"\{([^}]*)\} copy\(", text)
             if "S(" not in layout]
         assert not copies, (name, copies)
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE))
+def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
+                                              no_persistent_cache):
+    arch, slots, max_seq = _DECODE[case]
+    m = build_model(dataclasses.replace(get_config(arch), n_layers=2))
+    params = _on_chip(jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))),
+                      one_chip)
+    cache = _on_chip(jax.eval_shape(lambda: m.init_cache(slots, max_seq)),
+                     one_chip)
+    token = jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip)
+    compiled = jax.jit(m.decode_step, donate_argnums=(2,)).lower(
+        params, token, cache).compile()
+    _assert_in_place(compiled, cache, slots, max_seq)
+
+
+@pytest.mark.parametrize("case", sorted(_DECODE))
+def test_insert_writes_cache_in_place_for_v5e(case, one_chip,
+                                              no_persistent_cache):
+    """The slot insert, jitted as ``ServeEngine`` jits it (decode cache and
+    ``last_token`` donated, the slot a traced index), writes the prefilled
+    row into the decode cache without copying it."""
+    arch, slots, max_seq = _DECODE[case]
+    m = build_model(dataclasses.replace(get_config(arch), n_layers=2))
+    cache = _on_chip(jax.eval_shape(lambda: m.init_cache(slots, max_seq)),
+                     one_chip)
+    row = _on_chip(jax.eval_shape(lambda: m.init_cache(1, max_seq)),
+                   one_chip)
+    scalar, last, tok = (jax.ShapeDtypeStruct(s, i32, sharding=one_chip)
+                         for s in ((), (slots, 1), (1, 1)))
+    compiled = jax.jit(insert_slot, donate_argnums=(0, 3)).lower(
+        cache, row, scalar, last, tok).compile()
+    _assert_in_place(compiled, cache, slots, max_seq)
+    # the new cache is the donated one
+    size = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= size
