@@ -5,7 +5,7 @@ import importlib
 from typing import Dict, List
 
 from repro.configs.base import (  # noqa: F401
-    ArchConfig, AttnConfig, MLAConfig, MoEConfig, SSMConfig, ShapeConfig,
+    ArchConfig, AttnConfig, KDAConfig, MLAConfig, MoEConfig, SSMConfig, ShapeConfig,
     reduced, dtype_of,
 )
 
@@ -21,6 +21,7 @@ _MODULES = {
     "zamba2-1.2b": "zamba2_1_2b",
     "internvl2-76b": "internvl2_76b",
     "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "kimi-linear-48b-a3b": "kimi_linear_48b_a3b",
 }
 
 

@@ -56,6 +56,23 @@ class MLAConfig:
     kv_lora_rank: int
     rope_dim: int
     v_head_dim: int
+    # False: the ``rope_dim`` columns stay in q and in the shared key but
+    # are not rotated (Kimi Linear's ``mla_use_nope``)
+    rope: bool = True
+
+
+@dataclasses.dataclass(frozen=True)
+class KDAConfig:
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692 §3): per head a
+    ``head_dim`` x ``head_dim`` float32 state updated by a gated delta rule
+    with a per-channel decay.  q, k and v each pass a causal depthwise
+    convolution of width ``d_conv``; the decay and the output gate are
+    low-rank projections of rank ``head_dim``.  ``mla_layers`` are the
+    (0-based) layers that run latent attention in place of KDA."""
+    n_heads: int
+    head_dim: int
+    d_conv: int = 4
+    mla_layers: Tuple[int, ...] = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,8 +94,8 @@ class AttnConfig:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                       # dense | moe | mla_moe | ssm | hybrid
-                                      # | encdec | vlm
+    family: str                       # dense | moe | mla_moe | kda_mla_moe
+                                      # | ssm | hybrid | encdec | vlm
     n_layers: int
     d_model: int
     d_ff: int
@@ -86,7 +103,8 @@ class ArchConfig:
     attn: Optional[AttnConfig] = None
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
-    # mla_moe: the first ``first_k_dense`` layers have a SwiGLU of width
+    kda: Optional[KDAConfig] = None   # family kda_mla_moe
+    # mla_moe, kda_mla_moe: the first ``first_k_dense`` layers have a SwiGLU of width
     # ``d_ff`` in place of the expert layer
     first_k_dense: int = 0
     # hybrid (zamba2): one SHARED attention block applied every `attn_every`
@@ -115,6 +133,18 @@ class ArchConfig:
     source: str = ""                  # provenance note [source; tier]
 
     # ---- derived ----------------------------------------------------------
+    @property
+    def held_experts(self) -> bool:
+        """The expert layer is ``moe_held`` (DeepSeek-V3 routing, one chip's
+        share of the experts, no token dropped)."""
+        return self.family in HELD_EXPERT_FAMILIES
+
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """Per layer of a ``kda_mla_moe`` stack: "kda" or "mla"."""
+        mla = set(self.kda.mla_layers) if self.kda else set()
+        return tuple("mla" if i in mla else "kda"
+                     for i in range(self.n_layers))
+
     def layer_windows(self) -> Tuple[int, ...]:
         """Static per-layer attention window (-1 == global) for the decoder."""
         a = self.attn
@@ -177,6 +207,9 @@ class ArchConfig:
         return dense_total + L * m.top_k * e_mlp
 
 
+HELD_EXPERT_FAMILIES = ("mla_moe", "kda_mla_moe")
+
+
 @dataclasses.dataclass(frozen=True)
 class ShapeConfig:
     name: str
@@ -229,8 +262,8 @@ def reduced(cfg: ArchConfig, *, n_layers: int = 2, d_model: int = 64,
             local_window=min(a.local_window, 16) if a.local_window else 0)
     if cfg.attn is not None and cfg.attn.mla is not None:
         changes["attn"] = dataclasses.replace(
-            changes["attn"], mla=MLAConfig(kv_lora_rank=32, rope_dim=8,
-                                           v_head_dim=16))
+            changes["attn"], mla=dataclasses.replace(
+                cfg.attn.mla, kv_lora_rank=32, rope_dim=8, v_head_dim=16))
     if cfg.moe is not None:
         m = cfg.moe
         n = min(m.n_experts, 4)
@@ -241,6 +274,11 @@ def reduced(cfg: ArchConfig, *, n_layers: int = 2, d_model: int = 64,
     if cfg.ssm is not None:
         changes["ssm"] = dataclasses.replace(
             cfg.ssm, d_state=16, head_dim=16, chunk=16)
+    if cfg.kda is not None:
+        # one whole period: a KDA layer, then latent attention
+        changes["kda"] = dataclasses.replace(
+            cfg.kda, n_heads=2, head_dim=16,
+            mla_layers=tuple(i for i in range(1, n_layers, 2)))
     if cfg.n_enc_layers:
         changes["n_enc_layers"] = 2
         changes["n_frames"] = 16

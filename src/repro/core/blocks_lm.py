@@ -54,7 +54,8 @@ def build_block_table(model: Model, shape: ShapeConfig,
     pos = jax.ShapeDtypeStruct((b, s), jnp.int32)
     toks = jax.ShapeDtypeStruct((b, s), jnp.int32)
 
-    layer_sp = (T.layer_specs(cfg, dims) if cfg.family != "encdec" else None)
+    layer_sp = (T.layer_specs(cfg, dims)
+                if cfg.family not in ("encdec", "kda_mla_moe") else None)
     lp = _spec_struct(layer_sp, dt) if layer_sp is not None else None
 
     blocks: List[BlockDef] = []
@@ -88,6 +89,9 @@ def build_block_table(model: Model, shape: ShapeConfig,
         prog.append(Segment((i_attn, i_mlp), cfg.first_k_dense))
         prog.append(Segment((i_attn, i_moe),
                             cfg.n_layers - cfg.first_k_dense))
+
+    elif cfg.family == "kda_mla_moe":
+        prog += _kda_mla_moe_segments(cfg, dims, shape, x, pos, dt, add)
 
     elif cfg.family in ("dense", "moe", "vlm"):
         win = jnp.int32(-1)
@@ -174,7 +178,7 @@ def build_block_table(model: Model, shape: ShapeConfig,
 
     # ---- virtual (signature-only) blocks -----------------------------------
     # one entry per expert the router scores, held here or not
-    if cfg.family in ("moe", "mla_moe"):
+    if cfg.family == "moe" or cfg.held_experts:
         for e in range(cfg.moe.n_experts):
             add(f"expert_tok_{e}", IRCost(0, 0, 0), virtual=True,
                 dyn_key="expert_tokens", dyn_index=e)
@@ -194,6 +198,56 @@ def build_block_table(model: Model, shape: ShapeConfig,
                                  cost_flops=bl.cost_flops * scale)
              for bl in table.blocks], table.program)
     return table
+
+
+def _kda_mla_moe_segments(cfg, dims, shape, x, pos, dt, add):
+    """One segment per run of the layer schedule, with a block per
+    attention kind and per feed-forward kind.  A KDA block is costed by the
+    work the step runs: the chunked recurrence over the sequence, or, in a
+    decode, one step of every row's state."""
+    from repro.models import kda as K
+    from repro.models import moe as M
+    # a layer of each kind (attention "kda"/"mla", feed-forward "mlp"/"moe")
+    plan = T.layer_plan(cfg)
+    stack = {}
+    for _, layers in plan:
+        for st, *_ in layers:
+            for part in st.split("_")[1:]:
+                stack.setdefault(part, _spec_struct(
+                    T.kda_layer_specs(cfg, st.split("_", 1)[1]), dt))
+    if shape.kind == "decode":
+        k = cfg.kda
+        state = jax.ShapeDtypeStruct(
+            (x.shape[0], k.n_heads, k.head_dim, k.head_dim), jnp.float32)
+        conv = jax.ShapeDtypeStruct(
+            (x.shape[0], k.d_conv - 1, K.conv_dim(cfg)), dt)
+
+        def kda(p, xx, st, cv):
+            h = L.rmsnorm(p["attn_norm"], xx, cfg.norm_eps)
+            return K.kda_decode(p["kda"], cfg, h, st, cv, dt)[0]
+        kda_args = (state, conv)
+    else:
+        def kda(p, xx):
+            return T._kda_block(jax.tree.map(lambda a: a[None], p),
+                                jnp.int32(0), cfg, xx)[0]
+        kda_args = ()
+    costs = {
+        "kda": lambda: trace_cost(kda, stack["kda"], x, *kda_args),
+        "mla": lambda: trace_cost(
+            lambda p, xx, pp: T._mla_attn_block(p, cfg, dims, xx, pp)[0],
+            stack["mla"], x, pos),
+        "mlp": lambda: trace_cost(
+            lambda p, xx: T._mlp_block(p, cfg, xx, plus_one=False,
+                                       aux=T._aux_zero(cfg)),
+            stack["mlp"], x),
+        "moe": lambda: trace_cost(
+            lambda p, xx: M.moe_held(p["moe"], cfg, xx)[0], stack["moe"], x),
+    }
+    blocks = {part: add(part, cost()) for part, cost in costs.items()
+              if part in stack}
+    return [Segment(tuple(blocks[part] for st, *_ in layers
+                          for part in st.split("_")[1:]), reps)
+            for reps, layers in plan]
 
 
 @functools.lru_cache(maxsize=32)

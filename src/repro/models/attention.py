@@ -332,16 +332,19 @@ def mla_project(params, a: AttnConfig, x: jax.Array, positions: jax.Array,
                 dtype):
     """x: [B,S,d] -> q_nope [B,S,H,nope], q_pe [B,S,H,rope], c_kv [B,S,rank]
     (normed) and k_pe [B,S,rope]; RoPE (interleaved pairs) on the rope
-    parts."""
+    parts, unless ``a.mla.rope`` is off (NoPE: they stay as projected)."""
     m = a.mla
     x = x.astype(dtype)
     q = jnp.einsum("bsd,dhk->bshk", x, params["wq"]["kernel"].astype(dtype))
-    q_pe = L.apply_rope_interleaved(q[..., a.head_dim:], positions,
-                                    a.rope_theta)
+    q_pe = q[..., a.head_dim:]
+    if m.rope:
+        q_pe = L.apply_rope_interleaved(q_pe, positions, a.rope_theta)
     kv = x @ params["wkv_a"]["kernel"].astype(dtype)
     c_kv = L.rmsnorm(params["kv_norm"], kv[..., :m.kv_lora_rank])
-    k_pe = L.apply_rope_interleaved(kv[..., None, m.kv_lora_rank:],
-                                    positions, a.rope_theta)[..., 0, :]
+    k_pe = kv[..., None, m.kv_lora_rank:]
+    if m.rope:
+        k_pe = L.apply_rope_interleaved(k_pe, positions, a.rope_theta)
+    k_pe = k_pe[..., 0, :]
     return q[..., :a.head_dim], q_pe, c_kv, k_pe
 
 

@@ -3,8 +3,11 @@
 Attention decode carries the stacked cache through the layer scan and writes
 each row's new entry in place at [layer, row, length], so no layer's slice is
 copied out and back; cache writes are per-row scatters so continuous batching
-(per-row lengths) works.  Prefill runs the full-sequence layer stack once and
-writes every layer's keys and values (or latent entries) into the cache.
+(per-row lengths) works.  KDA decode (``kda_mla_moe``) carries its stacked
+recurrent and convolution states the same way and writes each layer's whole
+slice in place.  Prefill runs the full-sequence layer stack once and writes
+every layer's keys and values (or latent entries, or final states) into the
+cache.
 """
 from __future__ import annotations
 
@@ -16,12 +19,14 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.distributed.sharding import shard
 from repro.models import attention as A
+from repro.models import kda as K
 from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models import kvcache as KC
 from repro.models.transformer import (
     ModelDims, _aux_zero, _hybrid_groups, _mlp_block, _shared_attn_block,
-    embed_tokens, unembed,
+    embed_tokens, kda_mla_moe_stack, run_plan, take_layer, unembed,
+    write_kda_states,
 )
 
 
@@ -69,6 +74,8 @@ def lm_prefill(params, cfg: ArchConfig, dims: ModelDims, tokens,
         for key, val in (("c_kv", c_kv), ("k_pe", k_pe)):  # [L,B,s,*]
             cache[key] = jax.lax.dynamic_update_slice(
                 cache[key], val.astype(cache[key].dtype), (0, 0, 0, 0))
+    elif cfg.family == "kda_mla_moe":
+        x, aux = kda_mla_moe_stack(params, cfg, dims, x, positions, cache)
     elif cfg.family in ("dense", "moe", "vlm"):
         x, aux, kv = decoder_stack(params, cfg, dims, x, positions,
                                    collect_kv=True, plus_one=plus_one)
@@ -162,6 +169,9 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
     if cfg.family == "mla_moe":
         x, aux = _mla_moe_decode(params, cfg, x, positions, lengths, cache,
                                  aux)
+    elif cfg.family == "kda_mla_moe":
+        x, aux = _kda_mla_moe_decode(params, cfg, x, positions, lengths,
+                                     cache, aux)
     elif cfg.family in ("dense", "moe", "vlm"):
         def body(carry, xs):
             xc, aux, k_all, v_all = carry
@@ -245,6 +255,35 @@ def lm_decode(params, cfg: ArchConfig, dims: ModelDims, token,
     return logits, cache, aux
 
 
+def _mla_decode_block(p, cfg: ArchConfig, xc, positions, lengths, rows,
+                      c_all, pe_all, i, *, pe_select: bool = False):
+    """Latent attention of one token per row (``rows``, their indices),
+    absorbed over layer ``i`` of the stacked latent cache, after writing
+    the rows' entries there in place; returns (xc, c_all, pe_all).
+
+    ``pe_select`` writes ``k_pe`` by a select over the layer's slice in
+    place of a scatter: in a scan that also steps KDA states the compiler
+    lays the 64-wide ``k_pe`` out again for the scatter and copies it
+    whole, and the select keeps it as it lies, for one more pass over the
+    layer's slice."""
+    dt = xc.dtype
+    with jax.named_scope("nugget_block_mla"):
+        h = L.rmsnorm(p["attn_norm"], xc, cfg.norm_eps)
+        q_nope, q_pe, c, pe = A.mla_project(p["attn"], cfg.attn, h,
+                                            positions, dt)
+        c_all = c_all.at[i, rows, lengths].set(c[:, 0].astype(c_all.dtype))
+        if pe_select:
+            at = jnp.arange(pe_all.shape[2])[None, :] == lengths[:, None]
+            pe_all = jax.lax.dynamic_update_index_in_dim(pe_all, jnp.where(
+                at[..., None], pe.astype(pe_all.dtype), pe_all[i]), i, 0)
+        else:
+            pe_all = pe_all.at[i, rows, lengths].set(pe[:, 0].astype(
+                pe_all.dtype))
+        ctx = A.mla_decode(p["attn"], cfg.attn, q_nope, q_pe, c_all[i],
+                           pe_all[i], lengths + 1, dt)
+        return xc + A.mla_out(p["attn"], ctx, dt), c_all, pe_all
+
+
 def _mla_moe_decode(params, cfg: ArchConfig, x, positions, lengths,
                     cache: Dict[str, Any], aux: Dict):
     """One token per row through the dense then the expert layers, attention
@@ -252,23 +291,13 @@ def _mla_moe_decode(params, cfg: ArchConfig, x, positions, lengths,
     carry: each layer writes its row entries and reads its own slice, so no
     layer's cache is copied out and back."""
     rows = jnp.arange(x.shape[0])
-    dt = x.dtype
 
     def body(carry, xs):
         xc, aux, c_all, pe_all = carry
         p, i = xs
         aux = dict(aux)
-        with jax.named_scope("nugget_block_mla"):
-            h = L.rmsnorm(p["attn_norm"], xc, cfg.norm_eps)
-            q_nope, q_pe, c, pe = A.mla_project(p["attn"], cfg.attn, h,
-                                                positions, dt)
-            c_all = c_all.at[i, rows, lengths].set(c[:, 0].astype(
-                c_all.dtype))
-            pe_all = pe_all.at[i, rows, lengths].set(pe[:, 0].astype(
-                pe_all.dtype))
-            ctx = A.mla_decode(p["attn"], cfg.attn, q_nope, q_pe, c_all[i],
-                               pe_all[i], lengths + 1, dt)
-            xc = xc + A.mla_out(p["attn"], ctx, dt)
+        xc, c_all, pe_all = _mla_decode_block(p, cfg, xc, positions, lengths,
+                                              rows, c_all, pe_all, i)
         xc = _mlp_block(p, cfg, xc, plus_one=False, aux=aux)
         return (xc, aux, c_all, pe_all), None
 
@@ -280,3 +309,40 @@ def _mla_moe_decode(params, cfg: ArchConfig, x, positions, lengths,
                                           jnp.arange(nd, cfg.n_layers)))
     x, aux, cache["c_kv"], cache["k_pe"] = carry
     return x, aux
+
+
+def _kda_mla_moe_decode(params, cfg: ArchConfig, x, positions, lengths,
+                        cache: Dict[str, Any], aux: Dict):
+    """One token per row through the layers in schedule order: a KDA layer
+    steps its rows' recurrent and convolution states, a latent layer
+    attends absorbed over its cache.  Every stacked state rides in the
+    scans' carry; each layer reads its own slice and writes it back in
+    place, so no layer's state is copied out and back."""
+    dt = x.dtype
+    rows = jnp.arange(x.shape[0])
+
+    def layer(stack, kind, i, j, xc, aux, c):
+        sp = params[stack]
+        if kind == "kda":
+            with jax.named_scope("nugget_block_kda"):
+                p = take_layer({"attn_norm": sp["attn_norm"],
+                                "kda": sp["kda"]}, i)
+                h = L.rmsnorm(p["attn_norm"], xc, cfg.norm_eps)
+                y, state, conv = K.kda_decode(p["kda"], cfg, h,
+                                              c["kda_state"][j],
+                                              c["kda_conv"][j], dt)
+                write_kda_states(c, j, state, conv)
+                xc = xc + y
+        else:
+            p = take_layer({"attn_norm": sp["attn_norm"],
+                            "attn": sp["attn"]}, i)
+            xc, c["c_kv"], c["k_pe"] = _mla_decode_block(
+                p, cfg, xc, positions, lengths, rows, c["c_kv"], c["k_pe"],
+                j, pe_select=True)
+        ffn = "moe" if "moe" in sp else "mlp"
+        xc = _mlp_block(take_layer({"mlp_norm": sp["mlp_norm"],
+                                    ffn: sp[ffn]}, i),
+                        cfg, xc, plus_one=False, aux=aux)
+        return xc, aux, c
+
+    return run_plan(cfg, layer, x, aux, cache)

@@ -13,6 +13,7 @@ from repro.configs.base import ArchConfig, dtype_of
 from repro.distributed.sharding import ShardingPlan
 from repro.models import decode as D
 from repro.models import encdec as ED
+from repro.models import kda as K
 from repro.models import kvcache as KC
 from repro.models import layers as L
 from repro.models import ssm as S
@@ -88,6 +89,15 @@ class Model:
             mla = cfg.attn.mla
             return KC.init_cache(cfg.n_layers, batch, max_seq, 0, 0, dt,
                                  mla=(mla.kv_lora_rank, mla.rope_dim))
+        if cfg.family == "kda_mla_moe":
+            mla, k = cfg.attn.mla, cfg.kda
+            n_mla = cfg.layer_kinds().count("mla")
+            return KC.init_cache(
+                n_mla, batch, max_seq, 0, 0, dt,
+                mla=(mla.kv_lora_rank, mla.rope_dim),
+                kda=dict(n_layers=cfg.n_layers - n_mla, n_heads=k.n_heads,
+                         head_dim=k.head_dim, d_conv=k.d_conv,
+                         conv_dim=K.conv_dim(cfg)))
         if cfg.family == "hybrid":
             ae, n_groups, _ = T._hybrid_groups(cfg)
             return KC.init_cache(n_groups, batch, max_seq, kv_pad, hd, dt,

@@ -7,10 +7,11 @@ required — the only collectives are the contraction psums XLA already inserts
 for tensor parallelism.  Router statistics (tokens/expert, dropped tokens) are
 returned as dynamic Nugget-signature entries (DESIGN.md §2).
 
-:func:`moe_held` is the serving layer of the ``mla_moe`` family: it holds
-``n_held`` of the router's experts (one chip's share under expert
-parallelism), routes over all of them, and computes its own experts' part of
-the result for every token routed to them, with no token dropped.
+:func:`moe_held` is the serving layer of the ``mla_moe`` and ``kda_mla_moe``
+families: it holds ``n_held`` of the router's experts (one chip's share
+under expert parallelism), routes over all of them, and computes its own
+experts' part of the result for every token routed to them, with no token
+dropped.
 """
 from __future__ import annotations
 
@@ -39,7 +40,7 @@ def moe_specs(cfg: ArchConfig) -> Dict[str, Any]:
         "wo": ParamSpec((ne, fe, d), ("experts", "expert_mlp", "embed"),
                         "scaled"),
     }
-    if cfg.family == "mla_moe":
+    if cfg.held_experts:
         # e_score_correction_bias: moves selection only, not the gates
         specs["router"]["score_bias"] = ParamSpec((m.n_experts,), (None,),
                                                   "normal")

@@ -4,7 +4,8 @@ heads of 128, sequence 2048, decode cache 4096) and mamba2-780m SSD (48
 heads of 64, state 128, chunk 256).  What Mosaic refuses here it would
 refuse on the chip.  The batched decode and the slot insert, compiled as
 the serving engine compiles them (cache donated), write the cache in
-place: no cache-sized temporary, no copy of the stacked cache.
+place: no temporary as large as one layer's slice of a cache array, no copy
+of a stacked cache array, bf16 or float32.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers import
@@ -92,17 +93,32 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
 
 
 # the cache arrays a decode reads whole
-_STACKED = ("k", "v", "c_kv", "k_pe")
-# case -> (config, decode slots, positions a slot holds): two layers at the
-# published widths.  ``bf16_cache`` is the qwen3-1.7b serving cell's decode
-# batch, ``steady_shape`` the steady cell's (1824 positions, not a multiple
-# of 128); ``mla_moe`` is the control, whose decode always carried its
-# cache: Moonlight's leading dense layer and one expert layer.
+_STACKED = ("k", "v", "c_kv", "k_pe", "kda_state", "kda_conv")
+# case -> (config, decode slots, positions a slot holds, layers, experts
+# held; 0 = all) at the published widths.  ``bf16_cache`` is the qwen3-1.7b
+# serving cell's decode batch, ``steady_shape`` the steady cell's (1824
+# positions, not a multiple of 128); ``mla_moe`` is the control, whose
+# decode always carried its cache: Moonlight's leading dense layer and one
+# expert layer; ``kda_mla_moe`` is Kimi Linear's cell's batch and share of
+# the experts over its dense KDA layer and two whole periods of its
+# schedule (3 KDA + 1 latent layer), so that, as in the cell, one scan
+# steps the float32 KDA states and writes the bf16 latent cache.
 _DECODE = {
-    "bf16_cache": ("qwen3-1.7b", 32, 768),
-    "steady_shape": ("qwen3-1.7b", 16, 1824),
-    "mla_moe": ("moonlight-16b-a3b", 32, 768),
+    "bf16_cache": ("qwen3-1.7b", 32, 768, 2, 0),
+    "steady_shape": ("qwen3-1.7b", 16, 1824, 2, 0),
+    "mla_moe": ("moonlight-16b-a3b", 32, 768, 2, 0),
+    "kda_mla_moe": ("kimi-linear-48b-a3b", 128, 2560, 9, 8),
 }
+
+
+def _cut(arch, layers, held):
+    """``arch`` cut to ``layers``, its expert layers holding ``held``
+    experts (0: all of them)."""
+    cfg = dataclasses.replace(get_config(arch), n_layers=layers)
+    if held:
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe,
+                                                               n_held=held))
+    return cfg
 
 
 def _on_chip(tree, one_chip):
@@ -111,16 +127,20 @@ def _on_chip(tree, one_chip):
         tree)
 
 
-def _assert_in_place(compiled, cache, slots, max_seq):
-    # under one layer's k slice of qwen3's bf16 cache (50.3 MB at 32 x 768)
-    layer_slice = slots * max_seq * _KV * _HD * 2
+def _assert_in_place(compiled, cache):
+    stacked = [n for n in _STACKED if n in cache]
+    # under one layer's slice of the largest cache array (qwen3's k at 32 x
+    # 768: 50.3 MB; Kimi Linear's float32 KDA state at 128 slots: 268 MB)
+    layer_slice = max(cache[n].size // cache[n].shape[0]
+                      * cache[n].dtype.itemsize for n in stacked)
     assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
     # no copy in device memory (HBM) of a whole cache array; one into the
     # core's fast memory (layout tagged S(1)) stages a slice
     text = compiled.as_text()
-    for name in [n for n in _STACKED if n in cache]:
+    for name in stacked:
         a = cache[name]
-        shape = f"bf16[{','.join(map(str, a.shape))}]"
+        dtype = {"bfloat16": "bf16", "float32": "f32"}[a.dtype.name]
+        shape = f"{dtype}[{','.join(map(str, a.shape))}]"
         copies = [layout for layout in re.findall(
             re.escape(shape) + r"\{([^}]*)\} copy\(", text)
             if "S(" not in layout]
@@ -130,8 +150,8 @@ def _assert_in_place(compiled, cache, slots, max_seq):
 @pytest.mark.parametrize("case", sorted(_DECODE))
 def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
                                               no_persistent_cache):
-    arch, slots, max_seq = _DECODE[case]
-    m = build_model(dataclasses.replace(get_config(arch), n_layers=2))
+    arch, slots, max_seq, layers, held = _DECODE[case]
+    m = build_model(_cut(arch, layers, held))
     params = _on_chip(jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0))),
                       one_chip)
     cache = _on_chip(jax.eval_shape(lambda: m.init_cache(slots, max_seq)),
@@ -139,7 +159,7 @@ def test_decode_writes_cache_in_place_for_v5e(case, one_chip,
     token = jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip)
     compiled = jax.jit(m.decode_step, donate_argnums=(2,)).lower(
         params, token, cache).compile()
-    _assert_in_place(compiled, cache, slots, max_seq)
+    _assert_in_place(compiled, cache)
 
 
 @pytest.mark.parametrize("case", sorted(_DECODE))
@@ -148,8 +168,8 @@ def test_insert_writes_cache_in_place_for_v5e(case, one_chip,
     """The slot insert, jitted as ``ServeEngine`` jits it (decode cache and
     ``last_token`` donated, the slot a traced index), writes the prefilled
     row into the decode cache without copying it."""
-    arch, slots, max_seq = _DECODE[case]
-    m = build_model(dataclasses.replace(get_config(arch), n_layers=2))
+    arch, slots, max_seq, layers, held = _DECODE[case]
+    m = build_model(_cut(arch, layers, held))
     cache = _on_chip(jax.eval_shape(lambda: m.init_cache(slots, max_seq)),
                      one_chip)
     row = _on_chip(jax.eval_shape(lambda: m.init_cache(1, max_seq)),
@@ -158,7 +178,7 @@ def test_insert_writes_cache_in_place_for_v5e(case, one_chip,
                          for s in ((), (slots, 1), (1, 1)))
     compiled = jax.jit(insert_slot, donate_argnums=(0, 3)).lower(
         cache, row, scalar, last, tok).compile()
-    _assert_in_place(compiled, cache, slots, max_seq)
+    _assert_in_place(compiled, cache)
     # the new cache is the donated one
     size = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert compiled.memory_analysis().alias_size_in_bytes >= size
